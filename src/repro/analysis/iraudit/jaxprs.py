@@ -12,7 +12,7 @@ from collections import Counter
 from typing import Iterator
 
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def _sub_jaxprs(eqn) -> Iterator[jcore.Jaxpr]:

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -98,6 +97,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((H,), jnp.float32),
             pltpu.VMEM((H, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(positions, q, k, v)

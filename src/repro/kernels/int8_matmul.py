@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _int8_mm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_sc, *,
                     out_dtype):
@@ -63,8 +61,8 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array, sx: jax.Array, sw: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, sx, sw)
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -173,8 +172,9 @@ def _paged_decode_lse_kernel(bt_ref, pos_ref, own_ref, q_ref, k_ref, v_ref,
         l = l_sc[...]
         o_ref[0] = (acc_sc[...]
                     / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(l > 0.0, m_sc[...] + jnp.log(
+        lse = jnp.where(l > 0.0, m_sc[...] + jnp.log(
             jnp.maximum(l, 1e-30)), NEG_INF)
+        lse_ref[0] = lse.reshape(1, -1)
 
 
 def paged_decode_attention_lse(q: jax.Array, k_pool: jax.Array,
@@ -218,7 +218,9 @@ def paged_decode_attention_lse(q: jax.Array, k_pool: jax.Array,
         ],
         out_specs=(
             pl.BlockSpec((1, H, D), lambda b, j, bt, pos, own: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, bt, pos, own: (b, 0)),
+            # (1, 1, H): the last two block dims equal the array's, which
+            # Mosaic's (8, 128) tiling rule accepts for any H
+            pl.BlockSpec((1, 1, H), lambda b, j, bt, pos, own: (b, 0, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((H,), jnp.float32),
@@ -226,15 +228,17 @@ def paged_decode_attention_lse(q: jax.Array, k_pool: jax.Array,
             pltpu.VMEM((H, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, H), jnp.float32)),
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+                   jax.ShapeDtypeStruct((B, 1, H), jnp.float32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), positions.astype(jnp.int32),
       owned.astype(jnp.int32), q, k_pool, v_pool)
+    return o, lse[:, 0]
 
 
 def combine_lse(os: jax.Array, lses: jax.Array) -> jax.Array:
@@ -299,7 +303,8 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
       q, k_pool, v_pool)
@@ -368,7 +373,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), positions.astype(jnp.int32),
       q, k_pool, v_pool)

@@ -1,19 +1,24 @@
-"""Serving driver: continuous-batched generation through the Engine.
+"""Serving driver: continuous-batched generation through ``EngineSpec``.
 
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b
     PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --smoke \
         --requests 12
+
+Builds the stack the way every caller does (``EngineSpec`` -> ``Engine``
+-> ``PagedCachePool``), so without ``--smoke`` it serves the published
+widths with random weights on whatever device JAX finds, and prints that
+device first.
 """
 from __future__ import annotations
 
 import argparse
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.models import build_model, local_plan
-from repro.serving import Engine, EngineKnobs, Request
+from repro.launch.compile_cache import enable_compile_cache
+from repro.serving import EngineSpec, Request
 
 
 def main(argv=None) -> dict:
@@ -30,26 +35,20 @@ def main(argv=None) -> dict:
                     help="TAPAS frequency knob (1.0 = nominal clock)")
     ap.add_argument("--block-size", type=int, default=16,
                     help="paged KV pool block size (tokens)")
-    mode = ap.add_mutually_exclusive_group()
-    mode.add_argument("--paged", dest="paged", action="store_true",
-                      default=None, help="force the paged-KV pool")
-    mode.add_argument("--no-paged", dest="paged", action="store_false",
-                      help="force the legacy slot pool")
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke_config()
-    if cfg.encoder_only:
-        raise SystemExit(f"{cfg.name} is encoder-only; nothing to decode")
-    plan = local_plan(param_dtype=jnp.bfloat16)
-    model = build_model(cfg, plan)
-    params = model.init(jax.random.PRNGKey(0))
-    knobs = EngineKnobs(max_batch=args.max_batch or args.slots,
-                        freq_scale=args.freq_scale)
-    eng = Engine(model, params, max_seq=args.max_seq, n_slots=args.slots,
-                 knobs=knobs, paged=args.paged,
-                 block_size=args.block_size)
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print({"platform": dev.platform, "kind": dev.device_kind,
+           "count": jax.device_count()})
+    # fresh construction from CLI flags, not a copy of another spec
+    eng = EngineSpec(cfg, max_seq=args.max_seq, n_slots=args.slots,  # tapaslint: disable=TL004
+                     max_batch=args.max_batch,
+                     block_size=args.block_size).build()
+    eng.knobs.freq_scale = args.freq_scale
 
     rng = np.random.default_rng(0)
     for i in range(args.requests):
@@ -61,7 +60,6 @@ def main(argv=None) -> dict:
     stats = eng.run()
     gp = eng.goodput(ttft_slo=50.0, tbt_slo=5.0)
     out = {
-        "mode": "paged" if eng.paged else "slots",
         "completed": len(stats.completed),
         "decode_tokens": stats.decode_tokens,
         "prefill_tokens": stats.prefill_tokens,

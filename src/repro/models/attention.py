@@ -566,11 +566,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, *,
     q: (B, H, hd); k_pool/v_pool: (n_blocks, bs, K, hd);
     block_tables: (B, T) physical block ids; positions: (B,).
 
-    This is the XLA-native counterpart of the Pallas
-    ``kernels/paged_decode_attention.py`` kernel: the per-sequence logical
-    view is gathered from the pool through the block table, then masked by
-    position.  On TPU the kernel resolves the same gather in its BlockSpec
-    index map and never materialises the view.
+    The per-sequence logical view is gathered from the pool through the
+    block table, then masked by position.  This XLA gather is what the
+    serving path runs on every backend, TPU included.  The Pallas kernel
+    ``kernels/paged_decode_attention.py`` resolves the same gather in its
+    BlockSpec index map without materialising the view; it is tested
+    against this path and compile-tested for TPU, but not called here.
     """
     B, H = q.shape[:2]
     bs, K = k_pool.shape[1], k_pool.shape[2]
@@ -599,9 +600,10 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, starts, *,
     already be written into the pool (see ``gqa_prefill_paged``), so one
     gather serves both the cached context and the within-chunk causal
     part: position kpos is visible to chunk token c iff
-    kpos <= starts + c.  On TPU the Pallas counterpart
-    (``kernels/paged_decode_attention.paged_prefill_attention``) resolves
-    the gather in its BlockSpec index map instead.
+    kpos <= starts + c.  The serving path runs this XLA gather on every
+    backend; the Pallas counterpart
+    (``kernels/paged_decode_attention.paged_prefill_attention``) is
+    tested and compile-tested for TPU but not called here.
     """
     B, C, H = q.shape[:3]
     bs, K = k_pool.shape[1], k_pool.shape[2]

@@ -22,14 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.layers import pad_to_multiple
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.5
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:                                               # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
 @dataclass(frozen=True)
 class ShardPlan:
     mesh: Any = None                     # jax.sharding.Mesh or None (single host)
@@ -173,7 +165,7 @@ def shard_map_or_call(plan: ShardPlan, fn, in_specs, out_specs, *args):
     """
     if plan.mesh is None or plan.tp_axis is None:
         return fn(None, *args)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         partial(fn, plan.tp_axis), mesh=plan.mesh,
-        in_specs=in_specs, out_specs=out_specs, **_SHARD_MAP_KW)
+        in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return mapped(*args)

@@ -17,6 +17,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -555,6 +556,16 @@ class Model:
     def param_shardings(self):
         return self.plan.tree_shardings(self.param_axes(), self.cfg)
 
+    def init_placed(self, seed: int) -> Params:
+        """``init(PRNGKey(seed))`` compiled with the plan's parameter
+        shardings as its output layout, so each leaf is drawn, cast and
+        stored where it lives: no eager f32 draw of the stacked layers and
+        no copy through device 0 (the key too is made inside the program
+        from the host seed).  Bitwise equal to the eager ``init``."""
+        return _born_on_device(
+            lambda s: self.init(jax.random.PRNGKey(s)),
+            self.param_shardings(), np.uint32(seed))
+
     def _unembed_w(self, params: Params):
         if self.cfg.tie_embeddings and self.cfg.input_kind == "tokens":
             return params["embed"].T
@@ -1022,14 +1033,16 @@ class Model:
         Under a sharded plan each leaf is laid out over the mesh (the
         ``kv_blocks`` axis stripes physical block ids across ranks)."""
         cfg, plan = self.cfg, self.plan
-        c, _ = A.init_paged_attn_cache(cfg, plan, n_blocks, block_size, dtype)
-        cache = jax.tree.map(
-            lambda a: jnp.zeros((cfg.num_layers,) + a.shape, a.dtype),
-            {"attn": c})
-        sh = self.paged_cache_shardings()
-        if sh is not None:
-            cache = jax.device_put(cache, sh)
-        return cache
+
+        def zeros():
+            c, _ = A.init_paged_attn_cache(cfg, plan, n_blocks, block_size,
+                                           dtype)
+            return jax.tree.map(
+                lambda a: jnp.zeros((cfg.num_layers,) + a.shape, a.dtype),
+                {"attn": c})
+
+        # zeroed in place on each rank's stripe (or the default device)
+        return _born_on_device(zeros, self.paged_cache_shardings())
 
     def paged_cache_axes(self):
         """Logical axes of the layer-stacked paged pool leaves."""
@@ -1063,6 +1076,14 @@ class Model:
             cache["mamba"] = ms
             axes["mamba"] = max_
         return cache, axes
+
+
+def _born_on_device(fn, shardings, *args):
+    """Run ``fn`` compiled with ``shardings`` as its output layout (the
+    default device when None), so its results are created where they live
+    instead of built on one device and copied."""
+    kw = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(fn, **kw)(*args)
 
 
 def build_model(name_or_cfg, plan: ShardPlan) -> Model:

@@ -474,6 +474,7 @@ class Engine:
         self.drafters = {k: rebuild(m, p)
                          for k, (m, p) in self.drafters.items()}
         self.stats.shard_swaps += 1
+        del self.pool   # free the old pool before _bind zeroes the new one
         self._bind(self.variants[self.knobs.variant][0])
 
     # -- request lifecycle -------------------------------------------------

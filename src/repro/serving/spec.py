@@ -46,10 +46,8 @@ def serving_plan(shards: int = 1, **kw) -> ShardPlan:
         return local_plan(**kw)
     if jax.device_count() < shards:
         raise ValueError(
-            f"serving_plan(shards={shards}): only {jax.device_count()} "
-            f"devices visible; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={shards} before "
-            f"importing jax, or run on a {shards}-chip slice")
+            f"serving_plan(shards={shards}) needs {shards} chips; "
+            f"{jax.device_count()} visible")
     mesh = make_mesh_for(shards, want_model=shards)
     if mesh.shape["model"] != shards:
         raise ValueError(f"make_mesh_for could not build a model={shards} "
@@ -97,10 +95,7 @@ class EngineSpec:
 
     def _materialize(self, cfg: ArchConfig, plan: ShardPlan, seed: int):
         model = Model(cfg, plan)
-        params = model.init(jax.random.PRNGKey(seed))
-        if plan.mesh is not None:
-            params = jax.device_put(params, model.param_shardings())
-        return model, params
+        return model, model.init_placed(seed)
 
     def build(self, *, share: Engine | None = None) -> Engine:
         """Build (mesh, sharded params, pool, Engine) in one call.

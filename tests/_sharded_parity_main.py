@@ -150,6 +150,39 @@ def check_set_shards():
     ok("set_shards")
 
 
+def check_born_sharded():
+    """``EngineSpec.build`` at shards=2 creates params and pool in their
+    shardings: every leaf matches ``param_shardings()`` /
+    ``paged_cache_shardings()``, and no array held by one device is
+    copied onto the mesh on the way; the mesh's axes are Auto."""
+    from jax.sharding import AxisType
+    cfg = get_config("llama2-7b").smoke_config()
+    moved = []
+    real_put = jax.device_put
+
+    def watched_put(x, *a, **kw):
+        moved.extend(leaf.shape for leaf in jax.tree.leaves(x)
+                     if isinstance(leaf, jax.Array) and leaf.size > 1
+                     and len(leaf.sharding.device_set) == 1)
+        return real_put(x, *a, **kw)
+
+    jax.device_put = watched_put
+    try:
+        eng = EngineSpec(cfg, shards=2, max_seq=64, n_slots=4,
+                         block_size=8).build()
+    finally:
+        jax.device_put = real_put
+    assert not moved, f"single-device arrays copied onto the mesh: {moved}"
+    m = eng.model
+    assert m.plan.mesh.axis_types == (AxisType.Auto,) * 2
+    for tree, want in ((eng.params, m.param_shardings()),
+                       (eng.pool.cache, m.paged_cache_shardings())):
+        same = jax.tree.map(
+            lambda a, s: a.sharding.is_equivalent_to(s, a.ndim), tree, want)
+        assert all(jax.tree.leaves(same)), same
+    ok("born_sharded")
+
+
 def check_sharded_fleet():
     """An EngineFleet of sharded engines serves a pumped workload."""
     from repro.serving import EngineFleet
@@ -173,6 +206,7 @@ if __name__ == "__main__":
     check_engine_streams()
     check_pool_invariants()
     check_set_shards()
+    check_born_sharded()
     check_sharded_fleet()
     print("ALL_OK", flush=True)
     sys.exit(0)

@@ -31,7 +31,7 @@ def test_sharded_parity_multi_device():
     assert proc.returncode == 0, \
         f"sharded parity subprocess failed:\n{proc.stdout}\n{proc.stderr}"
     for name in ("core_parity", "engine_streams", "pool_invariants",
-                 "set_shards", "sharded_fleet"):
+                 "set_shards", "born_sharded", "sharded_fleet"):
         assert f"ok {name}" in proc.stdout, \
             f"missing check {name!r}:\n{proc.stdout}"
     assert "ALL_OK" in proc.stdout

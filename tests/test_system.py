@@ -27,9 +27,16 @@ def test_training_reduces_loss():
 
 
 def test_serve_driver_completes_requests():
+    from jax.experimental.compilation_cache import compilation_cache
+
     from repro.launch.serve import main
-    out = main(["--arch", "llama2-7b", "--smoke", "--requests", "5",
-                "--slots", "3", "--max-new", "8"])
+    cache_dir = jax.config.jax_compilation_cache_dir
+    try:
+        out = main(["--arch", "llama2-7b", "--smoke", "--requests", "5",
+                    "--slots", "3", "--max-new", "8"])
+    finally:   # the driver turns the persistent cache on; undo it here
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        compilation_cache.reset_cache()
     assert out["completed"] == 5
     assert out["decode_tokens"] > 0
 
