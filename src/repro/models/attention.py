@@ -645,10 +645,11 @@ def _paged_decode_core(axis, q, k_pool, v_pool, block_tables, positions,
     local_b = blk - off
     owned = (local_b >= 0) & (local_b < nb_loc)
     safe_b = jnp.where(owned, local_b, nb_loc)     # OOB on unowned -> dropped
-    k_pool = k_pool.at[safe_b, o_in_b].set(k_new.astype(k_pool.dtype),
-                                           mode="drop")
-    v_pool = v_pool.at[safe_b, o_in_b].set(v_new.astype(v_pool.dtype),
-                                           mode="drop")
+    with jax.named_scope("kv_append"):
+        k_pool = k_pool.at[safe_b, o_in_b].set(k_new.astype(k_pool.dtype),
+                                               mode="drop")
+        v_pool = v_pool.at[safe_b, o_in_b].set(v_new.astype(v_pool.dtype),
+                                               mode="drop")
     T = block_tables.shape[1]
     local_t = block_tables - off
     t_owned = (local_t >= 0) & (local_t < nb_loc)  # (B, T)
@@ -696,10 +697,11 @@ def _paged_prefill_core(axis, q, k_pool, v_pool, block_tables, starts,
     local_b = blk - off
     owned_w = valid & (local_b >= 0) & (local_b < nb_loc)
     safe_b = jnp.where(owned_w, local_b, nb_loc)   # OOB on unowned -> dropped
-    k_pool = k_pool.at[safe_b.reshape(-1), o_in_b.reshape(-1)].set(
-        k.reshape(B * C, K, -1).astype(k_pool.dtype), mode="drop")
-    v_pool = v_pool.at[safe_b.reshape(-1), o_in_b.reshape(-1)].set(
-        v.reshape(B * C, K, -1).astype(v_pool.dtype), mode="drop")
+    with jax.named_scope("kv_append"):
+        k_pool = k_pool.at[safe_b.reshape(-1), o_in_b.reshape(-1)].set(
+            k.reshape(B * C, K, -1).astype(k_pool.dtype), mode="drop")
+        v_pool = v_pool.at[safe_b.reshape(-1), o_in_b.reshape(-1)].set(
+            v.reshape(B * C, K, -1).astype(v_pool.dtype), mode="drop")
     T = block_tables.shape[1]
     local_t = block_tables - off
     t_owned = (local_t >= 0) & (local_t < nb_loc)
@@ -777,10 +779,11 @@ def gqa_prefill_paged(p: Params, x: jax.Array, cache: Params,
         blk = jnp.take_along_axis(block_tables, safe_pos // bs, axis=1)
         blk = jnp.where(valid, blk, 0)
         off = jnp.where(valid, safe_pos % bs, 0)
-        k_c = cache["k"].at[blk.reshape(-1), off.reshape(-1)].set(
-            k.reshape(B * C, K, -1).astype(cache["k"].dtype))
-        v_c = cache["v"].at[blk.reshape(-1), off.reshape(-1)].set(
-            v.reshape(B * C, K, -1).astype(cache["v"].dtype))
+        with jax.named_scope("kv_append"):
+            k_c = cache["k"].at[blk.reshape(-1), off.reshape(-1)].set(
+                k.reshape(B * C, K, -1).astype(cache["k"].dtype))
+            v_c = cache["v"].at[blk.reshape(-1), off.reshape(-1)].set(
+                v.reshape(B * C, K, -1).astype(cache["v"].dtype))
         o = paged_prefill_attention(q, k_c, v_c, block_tables, starts,
                                     scale=scale, kv_idx=idx)
     out = jnp.einsum("bshk,hkd->bsd", o, p["w_o"].astype(dt))
@@ -821,8 +824,9 @@ def gqa_decode_paged(p: Params, x: jax.Array, cache: Params,
         blk = jnp.take_along_axis(block_tables, (positions // bs)[:, None],
                                   axis=1)[:, 0]
         off = positions % bs
-        k_c = cache["k"].at[blk, off].set(k_new.astype(cache["k"].dtype))
-        v_c = cache["v"].at[blk, off].set(v_new.astype(cache["v"].dtype))
+        with jax.named_scope("kv_append"):
+            k_c = cache["k"].at[blk, off].set(k_new.astype(cache["k"].dtype))
+            v_c = cache["v"].at[blk, off].set(v_new.astype(cache["v"].dtype))
         o = paged_attention(q, k_c, v_c, block_tables, positions,
                             scale=scale, kv_idx=idx)
     out = jnp.einsum("bhk,hkd->bd", o, p["w_o"].astype(dt))

@@ -215,22 +215,24 @@ def block_prefill_paged(x, lp: Params, lc: Params, starts, lengths,
     per-sequence block table, so the chunk sees all previously written
     context (earlier chunks, shared prefix blocks) plus itself causally.
     """
-    h = _norm(x, lp["norm1"], cfg)
-    attn_out, attn_cache = A.gqa_prefill_paged(lp["attn"], h, lc["attn"],
-                                               starts, lengths, block_tables,
-                                               cfg, plan)
-    x = x + attn_out
-    h = _norm(x, lp["norm2"], cfg)
-    if cfg.n_experts:
-        y, _ = M.moe_ffn(lp["moe"], h, cfg, plan)
-    elif cfg.mlp_kind == "gelu2":
-        y = L.gelu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()})
-        y = plan.constrain(y, ("batch", "seq", "embed_act"), cfg)
-    else:
-        y = L.glu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()},
-                      activation=cfg.activation)
-        y = plan.constrain(y, ("batch", "seq", "embed_act"), cfg)
-    x = x + y
+    with jax.named_scope("attention"):
+        h = _norm(x, lp["norm1"], cfg)
+        attn_out, attn_cache = A.gqa_prefill_paged(
+            lp["attn"], h, lc["attn"], starts, lengths, block_tables, cfg,
+            plan)
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        h = _norm(x, lp["norm2"], cfg)
+        if cfg.n_experts:
+            y, _ = M.moe_ffn(lp["moe"], h, cfg, plan)
+        elif cfg.mlp_kind == "gelu2":
+            y = L.gelu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()})
+            y = plan.constrain(y, ("batch", "seq", "embed_act"), cfg)
+        else:
+            y = L.glu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()},
+                          activation=cfg.activation)
+            y = plan.constrain(y, ("batch", "seq", "embed_act"), cfg)
+        x = x + y
     return x, {"attn": attn_cache}
 
 
@@ -241,21 +243,22 @@ def block_decode_paged(x, lp: Params, lc: Params, positions, block_tables,
     lc holds this layer's slice of the global block pool; the attention
     write/gather goes through the per-sequence block table.
     """
-    h = _norm(x, lp["norm1"], cfg)
-    attn_out, attn_cache = A.gqa_decode_paged(lp["attn"], h, lc["attn"],
-                                              positions, block_tables,
-                                              cfg, plan)
-    x = x + attn_out
-    h = _norm(x, lp["norm2"], cfg)
-    if cfg.n_experts:
-        y, _ = M.moe_ffn(lp["moe"], h[:, None], cfg, plan)
-        y = y[:, 0]
-    elif cfg.mlp_kind == "gelu2":
-        y = L.gelu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()})
-    else:
-        y = L.glu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()},
-                      activation=cfg.activation)
-    x = x + y
+    with jax.named_scope("attention"):
+        h = _norm(x, lp["norm1"], cfg)
+        attn_out, attn_cache = A.gqa_decode_paged(
+            lp["attn"], h, lc["attn"], positions, block_tables, cfg, plan)
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        h = _norm(x, lp["norm2"], cfg)
+        if cfg.n_experts:
+            y, _ = M.moe_ffn(lp["moe"], h[:, None], cfg, plan)
+            y = y[:, 0]
+        elif cfg.mlp_kind == "gelu2":
+            y = L.gelu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()})
+        else:
+            y = L.glu_mlp(h, {k: v.astype(plan.compute_dtype) for k, v in lp["mlp"].items()},
+                          activation=cfg.activation)
+        x = x + y
     return x, {"attn": attn_cache}
 
 
@@ -697,8 +700,9 @@ class Model:
             return x, new_lc
 
         x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-        x = _norm(x, params["final_norm"], cfg)
-        logits = self._head(params, x)
+        with jax.named_scope("head"):
+            x = _norm(x, params["final_norm"], cfg)
+            logits = self._head(params, x)
         return logits, new_cache
 
     def decode_multi_paged(self, params, cache, tokens, positions,
@@ -743,8 +747,9 @@ class Model:
             # dead tail steps (every lane drained) skip the model at
             # runtime, so the engine can always launch `horizon` steps —
             # one jit variant — without paying for the unused tail
-            return jax.lax.cond(jnp.any(carry[3]), live_step, parked_step,
-                                carry)
+            with jax.named_scope("horizon_cond"):
+                return jax.lax.cond(jnp.any(carry[3]), live_step,
+                                    parked_step, carry)
 
         def parked_step(carry):
             B = carry[1].shape[0]
@@ -757,21 +762,22 @@ class Model:
             bt_eff = jnp.where(active[:, None], block_tables, 0)
             logits, cache = self.decode_step_paged(
                 params, cache, tokens, pos_eff, bt_eff)
-            if temps is None:
-                nxt = jnp.argmax(logits[:, : cfg.vocab_size],
-                                 axis=-1).astype(jnp.int32)
-            else:
-                dist = sampling_dist(logits[:, : cfg.vocab_size], temps,
-                                     top_ks)
-                keys = event_keys(base_keys, positions + 1, SALT_SAMPLE)
-                nxt = sample_from_dist(keys, dist, temps <= 0.0)
-            emitted = active
-            budgets = budgets - emitted.astype(jnp.int32)
-            done = emitted & ((budgets <= 0) | (nxt == eos_ids)
-                              | (positions + 1 >= max_len))
-            tokens = jnp.where(emitted, nxt, tokens)
-            positions = positions + emitted.astype(jnp.int32)
-            active = active & ~done
+            with jax.named_scope("sample"):
+                if temps is None:
+                    nxt = jnp.argmax(logits[:, : cfg.vocab_size],
+                                     axis=-1).astype(jnp.int32)
+                else:
+                    dist = sampling_dist(logits[:, : cfg.vocab_size], temps,
+                                         top_ks)
+                    keys = event_keys(base_keys, positions + 1, SALT_SAMPLE)
+                    nxt = sample_from_dist(keys, dist, temps <= 0.0)
+                emitted = active
+                budgets = budgets - emitted.astype(jnp.int32)
+                done = emitted & ((budgets <= 0) | (nxt == eos_ids)
+                                  | (positions + 1 >= max_len))
+                tokens = jnp.where(emitted, nxt, tokens)
+                positions = positions + emitted.astype(jnp.int32)
+                active = active & ~done
             carry = (cache, tokens, positions, active, budgets,
                      logits.astype(logits0.dtype))
             return carry, (nxt, emitted)
@@ -978,11 +984,12 @@ class Model:
             return x, new_lc
 
         x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-        x = _norm(x, params["final_norm"], cfg)
-        last = jnp.take_along_axis(
-            x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32),
-            axis=1)[:, 0]
-        return self._head(params, last), new_cache
+        with jax.named_scope("head"):
+            x = _norm(x, params["final_norm"], cfg)
+            last = jnp.take_along_axis(
+                x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(
+                    jnp.int32), axis=1)[:, 0]
+            return self._head(params, last), new_cache
 
     # ----- grads -----
     def canonicalize_grads(self, grads: Params) -> Params:

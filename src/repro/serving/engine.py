@@ -35,6 +35,16 @@ The paged decode hot path is device-resident end to end:
   prompt's full blocks up in the pool's content-hash index and reuses
   refcounted blocks written by earlier requests (shared system prompts
   are neither recomputed nor double-stored).
+
+Every ``step()`` is one ``engine.step`` profiler span
+(``jax.profiler.StepTraceAnnotation``) with children ``engine.housekeeping``,
+``engine.admit``, ``engine.prefill`` and ``engine.decode``; the last two
+split into ``.prepare`` (host arrays and uploads), ``.launch`` (the jitted
+call's dispatch), ``.sync`` (the device->host readback) and ``.commit``
+(bookkeeping), under the same names in every serving mode.  The profiler
+stamps them on the clock of its device planes.  Span arguments are built
+only while a profiler session records; without one a span costs about a
+microsecond.
 """
 from __future__ import annotations
 
@@ -48,6 +58,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.drafters import check_draft_pair
 from repro.core.traces import _stable_seed
@@ -56,8 +67,6 @@ from repro.models.transformer import (SALT_SAMPLE, Model, event_keys,
                                       sampling_dist)
 from repro.serving.kvcache import CachePool, PagedCachePool
 from repro.serving.request import Request
-
-STEP_WINDOW = 512       # recent step times retained for inspection
 
 
 @dataclass
@@ -102,16 +111,16 @@ class EngineStats:
     crashes: int = 0             # crash() invocations survived
     n_steps: int = 0             # recorded (working) scheduler steps
     step_time_total: float = 0.0  # running sum of freq-scaled step times
+    # admission: requests that claimed a lane, and their summed wait in
+    # the queue on the host's monotonic clock (submit or re-queue -> claim)
+    admitted: int = 0
+    queue_wait_s: float = 0.0
     completed: list = field(default_factory=list)
-    # recent window only — long-lived engines must not grow without bound
-    step_times: deque = field(
-        default_factory=lambda: deque(maxlen=STEP_WINDOW))
     _good_acc: dict = field(default_factory=dict, repr=False)
 
     def record_step(self, dt: float) -> None:
         self.n_steps += 1
         self.step_time_total += dt
-        self.step_times.append(dt)
 
     def goodput(self, *, ttft_slo: float, tbt_slo: float) -> float:
         """Tokens/s over completed requests meeting both SLOs.
@@ -254,6 +263,9 @@ class Engine:
         self._delayed: list = []      # (not_before_s, seq, req) heap
         self._delay_seq = 0
         self._has_deadlines = False
+        self._step_num = 0            # step() calls, the profiler's step_num
+        self._claims: list = []       # (context, shared, wait_ns) per lane
+        #                               claimed by the current admission
         self.stats = EngineStats()
         self._bind(model)
 
@@ -482,7 +494,24 @@ class Engine:
         self.stats.submitted += 1
         if req.deadline_ms is not None:
             self._has_deadlines = True
-        self.queue.append(req)
+        self._enqueue(req)
+
+    def _enqueue(self, req: Request, *, front: bool = False) -> None:
+        """Put a request in the admission queue (at the back, or at the
+        front for recompute) and start its queue wait."""
+        req.queued_ns = time.perf_counter_ns()
+        if front:
+            self.queue.appendleft(req)
+        else:
+            self.queue.append(req)
+
+    def _claim(self, req: Request, shared_tokens: int = 0) -> None:
+        """A request leaves the queue for a lane: count its wait."""
+        wait_ns = time.perf_counter_ns() - req.queued_ns
+        self.stats.admitted += 1
+        self.stats.queue_wait_s += wait_ns / 1e9
+        self._claims.append((len(req.prompt) + len(req.output),
+                             shared_tokens, wait_ns))
 
     @staticmethod
     def _context(req: Request) -> list:
@@ -532,7 +561,7 @@ class Engine:
         admission queue (no-op while the heap is empty)."""
         while self._delayed and self._delayed[0][0] <= now:
             _, _, req = heapq.heappop(self._delayed)
-            self.queue.append(req)
+            self._enqueue(req)
 
     def _expire_deadlines(self, now: float) -> None:
         """Evict every request past its deadline — queued, backing off,
@@ -652,7 +681,7 @@ class Engine:
             if drop:
                 dropped.append(req)
             else:
-                self.queue.appendleft(req)
+                self._enqueue(req, front=True)
         if drop:
             dropped.extend(self.queue)
             self.queue.clear()
@@ -692,13 +721,44 @@ class Engine:
         self._pending_waiter = None
         return sorted(out, key=lambda r: r.req_id)
 
+    # -- span arguments: built only while a profiler session records ------
+    def _step_args(self) -> dict:
+        return dict(queue=len(self.queue), active=len(self.active),
+                    prefilling=len(self.prefilling))
+
+    def _admit_args(self) -> dict:
+        return dict(requests=len(self._claims),
+                    prompt_tokens=sum(c[0] for c in self._claims),
+                    shared_tokens=sum(c[1] for c in self._claims),
+                    queue_wait_ms=sum(c[2] for c in self._claims) / 1e6)
+
+    @staticmethod
+    def _prefill_args(rows: int, tokens: int, finished: int) -> dict:
+        return dict(rows=rows, tokens=tokens, finished=finished)
+
+    def _decode_args(self, horizon: int, preempted: int) -> dict:
+        lane_of = self.pool.lane_of if self.paged else self.pool.slot_of
+        return dict(lanes=len(self.active),
+                    ctx_tokens=int(sum(self.pool.lengths[lane_of[rid]]
+                                       for rid in self.active)),
+                    horizon=horizon, preempted=preempted)
+
+    # -- admission and prefill ---------------------------------------------
     def _admit(self, now: float) -> None:
-        if self.paged:
-            if self.prefill_chunk:
+        """Claim lanes for queued requests (``engine.admit``)."""
+        self._claims = []
+        with TraceAnnotation("engine.admit") as span:
+            if not self.paged:
+                self._admit_slots(now)
+            elif self.prefill_chunk:
                 self._admit_chunked(now)
             else:
                 self._admit_paged(now)
-            return
+            if TraceAnnotation.is_enabled():
+                span.set_metadata(**self._admit_args())
+
+    def _admit_slots(self, now: float) -> None:
+        """Slot admission: one prefill launch per request."""
         while (self.queue and self.pool.has_free()
                and len(self.active) < self.knobs.max_batch
                and not self.knobs.paused):
@@ -706,15 +766,24 @@ class Engine:
                 self._reject(self.queue.popleft(), now)
                 continue
             req = self.queue.popleft()
-            prompt = jnp.asarray([self._context(req)], jnp.int32)
-            logits, cache = self._prefill_jit(self.params, prompt)
-            self.stats.prefill_tokens += prompt.shape[1]
-            self.stats.prefill_batches += 1
-            tok = int(self._next_from_prefill(
-                logits, [req], np.asarray([prompt.shape[1]]))[0])
-            self.stats.host_syncs += 1
-            self.pool.insert(req.req_id, cache, prompt.shape[1])
-            self._activate(req, tok, now)
+            self._claim(req)
+            with TraceAnnotation("engine.prefill") as span:
+                with TraceAnnotation("engine.prefill.prepare"):
+                    prompt = jnp.asarray([self._context(req)], jnp.int32)
+                    n = prompt.shape[1]
+                if TraceAnnotation.is_enabled():
+                    span.set_metadata(**self._prefill_args(1, n, 1))
+                with TraceAnnotation("engine.prefill.launch"):
+                    logits, cache = self._prefill_jit(self.params, prompt)
+                with TraceAnnotation("engine.prefill.sync"):
+                    tok = int(self._next_from_prefill(
+                        logits, [req], np.asarray([n]))[0])
+                with TraceAnnotation("engine.prefill.commit"):
+                    self.stats.prefill_tokens += n
+                    self.stats.prefill_batches += 1
+                    self.stats.host_syncs += 1
+                    self.pool.insert(req.req_id, cache, n)
+                    self._activate(req, tok, now)
 
     def _admit_paged(self, now: float) -> None:
         """Batched admission: drain the queue into length buckets, one
@@ -737,6 +806,7 @@ class Engine:
             if blocks_left < need:
                 break
             batch.append(self.queue.popleft())
+            self._claim(batch[-1])
             lanes_left -= 1
             blocks_left -= need
         if not batch:
@@ -747,36 +817,50 @@ class Engine:
                 _bucket(len(self._context(req)), hi=self.max_seq),
                 []).append(req)
         for s_bucket, reqs in sorted(groups.items()):
-            rows = len(reqs)
-            b_pad = _bucket(rows, lo=1)   # batch bucket bounds retraces too
-            tokens = np.zeros((b_pad, s_bucket), np.int32)
-            lengths = np.ones(b_pad, np.int32)
-            for i, req in enumerate(reqs):
-                ctx = self._context(req)
-                tokens[i, : len(ctx)] = ctx
-                lengths[i] = len(ctx)
-            logits, cache = self._prefill_jit(
-                self.params, jnp.asarray(tokens), jnp.asarray(lengths))
-            nxt = self._next_from_prefill(logits, reqs, lengths)
-            self.stats.prefill_batches += 1
-            self.stats.host_syncs += 1
-            d_cache = None
-            if self._draft_prefill_jit is not None:
-                # drafter KV for the same rows, scattered into the SAME
-                # blocks (the draft pool shares this pool's block tables)
-                _, d_cache = self._draft_prefill_jit(
-                    self._d_params, jnp.asarray(tokens),
-                    jnp.asarray(lengths))
-            for i, req in enumerate(reqs):
-                lane = self.pool.insert(req.req_id, cache, i,
-                                        int(lengths[i]))
-                if self._spec_on:
-                    self.pool.set_hist(lane, self._context(req))
-                if d_cache is not None:
-                    self.pool.insert_draft(req.req_id, d_cache, i,
-                                           int(lengths[i]))
-                self.stats.prefill_tokens += int(lengths[i])
-                self._activate(req, int(nxt[i]), now)
+            self._prefill_bucket(s_bucket, reqs, now)
+
+    def _prefill_bucket(self, s_bucket: int, reqs: list, now: float) -> None:
+        """One batched prefill launch over the admitted requests of one
+        length bucket (``engine.prefill``)."""
+        with TraceAnnotation("engine.prefill") as span:
+            with TraceAnnotation("engine.prefill.prepare"):
+                rows = len(reqs)
+                b_pad = _bucket(rows, lo=1)   # batch bucket bounds retraces
+                tokens = np.zeros((b_pad, s_bucket), np.int32)
+                lengths = np.ones(b_pad, np.int32)
+                for i, req in enumerate(reqs):
+                    ctx = self._context(req)
+                    tokens[i, : len(ctx)] = ctx
+                    lengths[i] = len(ctx)
+                tokens_d = jnp.asarray(tokens)
+                lengths_d = jnp.asarray(lengths)
+            if TraceAnnotation.is_enabled():
+                span.set_metadata(**self._prefill_args(
+                    rows, int(lengths[:rows].sum()), rows))
+            with TraceAnnotation("engine.prefill.launch"):
+                logits, cache = self._prefill_jit(self.params, tokens_d,
+                                                  lengths_d)
+                d_cache = None
+                if self._draft_prefill_jit is not None:
+                    # drafter KV for the same rows, scattered into the SAME
+                    # blocks (the draft pool shares this pool's block tables)
+                    _, d_cache = self._draft_prefill_jit(
+                        self._d_params, tokens_d, lengths_d)
+            with TraceAnnotation("engine.prefill.sync"):
+                nxt = self._next_from_prefill(logits, reqs, lengths)
+            with TraceAnnotation("engine.prefill.commit"):
+                self.stats.prefill_batches += 1
+                self.stats.host_syncs += 1
+                for i, req in enumerate(reqs):
+                    lane = self.pool.insert(req.req_id, cache, i,
+                                            int(lengths[i]))
+                    if self._spec_on:
+                        self.pool.set_hist(lane, self._context(req))
+                    if d_cache is not None:
+                        self.pool.insert_draft(req.req_id, d_cache, i,
+                                               int(lengths[i]))
+                    self.stats.prefill_tokens += int(lengths[i])
+                    self._activate(req, int(nxt[i]), now)
 
     def _admit_chunked(self, now: float) -> None:
         """Chunked-prefill admission: claim a lane plus every block the
@@ -807,6 +891,7 @@ class Engine:
             if lane is None:
                 break
             req = self.queue.popleft()
+            self._claim(req, len(shared) * self.pool.block_size)
             if self._spec_on:
                 self.pool.set_hist(lane, ctx)
             self.prefilling[req.req_id] = req
@@ -821,56 +906,70 @@ class Engine:
         launch over all prefilling rows, padded to shared buckets)."""
         if not self.prefilling:
             return 0
-        reqs = sorted(self.prefilling.values(), key=lambda r: r.req_id)
-        ctxs = [self._context(r) for r in reqs]
-        takes = [min(self.prefill_chunk,
-                     len(ctx) - self._prefill_pos[r.req_id])
-                 for r, ctx in zip(reqs, ctxs)]
-        c_pad = _bucket(max(takes), lo=min(16, self.prefill_chunk))
-        b_pad = _bucket(len(reqs), lo=1)
-        tokens = np.zeros((b_pad, c_pad), np.int32)
-        starts = np.zeros(b_pad, np.int32)
-        lens = np.zeros(b_pad, np.int32)
-        tables = np.zeros((b_pad, self.pool.blocks_per_seq), np.int32)
-        for i, (req, ctx, take) in enumerate(zip(reqs, ctxs, takes)):
-            p = self._prefill_pos[req.req_id]
-            tokens[i, :take] = ctx[p:p + take]
-            starts[i] = p
-            lens[i] = take
-            tables[i] = self.pool.block_tables[self.pool.lane_of[req.req_id]]
-        logits, self.pool.cache = self._prefill_chunk_jit(
-            self.params, self.pool.cache, jnp.asarray(tokens),
-            jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(tables))
-        if self._draft_chunk_jit is not None:
-            # stream the same chunk through the drafter into its pool
-            _, self.pool.draft_cache = self._draft_chunk_jit(
-                self._d_params, self.pool.draft_cache, jnp.asarray(tokens),
-                jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(tables))
-        self.stats.prefill_batches += 1
-        done_rows = [i for i, (req, ctx, take) in
-                     enumerate(zip(reqs, ctxs, takes))
-                     if self._prefill_pos[req.req_id] + take == len(ctx)]
-        nxt = None
-        if done_rows:
-            nxt = self._next_from_prefill(
-                logits, reqs,
-                np.asarray([self._prefill_pos[r.req_id] + t
-                            for r, t in zip(reqs, takes)]))
-            self.stats.host_syncs += 1
-        worked = 0
-        for i, (req, ctx, take) in enumerate(zip(reqs, ctxs, takes)):
-            self._prefill_pos[req.req_id] += take
-            self.stats.prefill_tokens += take
-            worked += take
-            if self._prefill_pos[req.req_id] == len(ctx):
-                rid = req.req_id
-                del self.prefilling[rid]
-                del self._prefill_pos[rid]
-                self.pool.set_length(self.pool.lane_of[rid], len(ctx))
-                if self.prefix_share:
-                    self.pool.register_prefix(rid, ctx)
-                self._activate(req, int(nxt[i]), now)
-        return worked
+        with TraceAnnotation("engine.prefill") as span:
+            with TraceAnnotation("engine.prefill.prepare"):
+                reqs = sorted(self.prefilling.values(),
+                              key=lambda r: r.req_id)
+                ctxs = [self._context(r) for r in reqs]
+                takes = [min(self.prefill_chunk,
+                             len(ctx) - self._prefill_pos[r.req_id])
+                         for r, ctx in zip(reqs, ctxs)]
+                c_pad = _bucket(max(takes), lo=min(16, self.prefill_chunk))
+                b_pad = _bucket(len(reqs), lo=1)
+                tokens = np.zeros((b_pad, c_pad), np.int32)
+                starts = np.zeros(b_pad, np.int32)
+                lens = np.zeros(b_pad, np.int32)
+                tables = np.zeros((b_pad, self.pool.blocks_per_seq),
+                                  np.int32)
+                for i, (req, ctx, take) in enumerate(zip(reqs, ctxs, takes)):
+                    p = self._prefill_pos[req.req_id]
+                    tokens[i, :take] = ctx[p:p + take]
+                    starts[i] = p
+                    lens[i] = take
+                    tables[i] = self.pool.block_tables[
+                        self.pool.lane_of[req.req_id]]
+                chunk = tuple(jnp.asarray(a)
+                              for a in (tokens, starts, lens, tables))
+                done_rows = [i for i, (req, ctx, take) in
+                             enumerate(zip(reqs, ctxs, takes))
+                             if self._prefill_pos[req.req_id] + take
+                             == len(ctx)]
+            if TraceAnnotation.is_enabled():
+                span.set_metadata(**self._prefill_args(
+                    len(reqs), sum(takes), len(done_rows)))
+            with TraceAnnotation("engine.prefill.launch"):
+                logits, self.pool.cache = self._prefill_chunk_jit(
+                    self.params, self.pool.cache, *chunk)
+                if self._draft_chunk_jit is not None:
+                    # stream the same chunk through the drafter into its pool
+                    _, self.pool.draft_cache = self._draft_chunk_jit(
+                        self._d_params, self.pool.draft_cache, *chunk)
+            nxt = None
+            if done_rows:
+                with TraceAnnotation("engine.prefill.sync"):
+                    nxt = self._next_from_prefill(
+                        logits, reqs,
+                        np.asarray([self._prefill_pos[r.req_id] + t
+                                    for r, t in zip(reqs, takes)]))
+            with TraceAnnotation("engine.prefill.commit"):
+                self.stats.prefill_batches += 1
+                if done_rows:
+                    self.stats.host_syncs += 1
+                worked = 0
+                for i, (req, ctx, take) in enumerate(zip(reqs, ctxs, takes)):
+                    self._prefill_pos[req.req_id] += take
+                    self.stats.prefill_tokens += take
+                    worked += take
+                    if self._prefill_pos[req.req_id] == len(ctx):
+                        rid = req.req_id
+                        del self.prefilling[rid]
+                        del self._prefill_pos[rid]
+                        self.pool.set_length(self.pool.lane_of[rid],
+                                             len(ctx))
+                        if self.prefix_share:
+                            self.pool.register_prefix(rid, ctx)
+                        self._activate(req, int(nxt[i]), now)
+            return worked
 
     def _preempt(self, req_ids: list) -> None:
         """Pool ran dry: drop these requests' blocks and requeue them at the
@@ -881,32 +980,16 @@ class Engine:
                 req = self.prefilling.pop(rid)
                 del self._prefill_pos[rid]
             self.pool.release(rid)
-            self.queue.appendleft(req)
+            self._enqueue(req, front=True)
             self.stats.preemptions += 1
 
-    def _decode_paged(self, now: float) -> int:
-        """Fused horizon decode: one jitted launch runs up to ``horizon``
-        steps for every active lane; the host syncs once to drain the
-        produced ``(tokens, emitted)`` horizon."""
-        budgets = {rid: req.max_new_tokens - len(req.output)
-                   for rid, req in self.active.items()}
-        # always launch `horizon` steps: the scan skips drained tail steps
-        # on-device (lax.cond), so num_steps stays one static value and
-        # the decode graph never retraces mid-run
-        n_eff = self.horizon
-        # allocate append blocks oldest-request-first; when the pool is
-        # exhausted the youngest actives are the ones preempted
-        victims = self.pool.ensure_append_blocks(
-            sorted(self.active), horizon=n_eff, budgets=budgets)
-        if victims:
-            self._preempt(victims)
-        if not self.active:
-            return 0
+    def _lane_arrays(self, budgets: dict) -> tuple:
+        """Per-lane decode inputs on the host: active mask, budgets, eos
+        ids, temperatures, top-k and sampling seeds."""
         width = self.pool.n_lanes
         active_mask = np.zeros(width, bool)
         budget_arr = np.zeros(width, np.int32)
         eos_arr = np.full(width, -1, np.int32)
-        sampled = any(r.temperature > 0 for r in self.active.values())
         temp_arr = np.zeros(width, np.float32)
         topk_arr = np.zeros(width, np.int32)
         seed_arr = np.zeros(width, np.int32)
@@ -919,180 +1002,222 @@ class Engine:
             temp_arr[lane] = req.temperature
             topk_arr[lane] = req.top_k
             seed_arr[lane] = self._req_seed(req)
-        # sampling arrays are only passed when some lane needs them, so
-        # an all-greedy engine runs the identical pre-sampling graph
-        extra = dict(temps=jnp.asarray(temp_arr),
-                     top_ks=jnp.asarray(topk_arr),
-                     seeds=jnp.asarray(seed_arr)) if sampled else {}
-        toks, emitted, _, (tok_f, pos_f, _, _), self.pool.cache = \
-            self._decode_multi_jit(
-                self.params, self.pool.cache, self.pool.last_tokens_dev(),
-                self.pool.positions(), self.pool.tables(),
-                jnp.asarray(active_mask), jnp.asarray(budget_arr),
-                jnp.asarray(eos_arr), num_steps=n_eff, max_len=self.max_seq,
-                **extra)
-        toks_h = np.asarray(toks)        # the horizon's single host sync
-        em_h = np.asarray(emitted)
-        self.stats.host_syncs += 1
-        self.stats.decode_syncs += 1
-        # the loop's final device state becomes the pool mirror — nothing
-        # is re-uploaded next launch; numpy mirrors updated below
-        self.pool.adopt_device("positions", pos_f)
-        self.pool.adopt_device("last_tokens", tok_f)
-        produced = 0
-        finished = []
-        for rid, req in list(self.active.items()):
-            lane = self.pool.lane_of[rid]
-            cnt = int(em_h[:, lane].sum())
-            req.output.extend(int(t) for t in toks_h[:cnt, lane])
-            produced += cnt
-            self.pool.lengths[lane] += cnt
-            self.pool.last_tokens[lane] = req.output[-1]
-            full = int(self.pool.lengths[lane]) + 1 > self.max_seq
-            if (len(req.output) >= req.max_new_tokens
-                    or (req.eos_id is not None
-                        and req.output[-1] == req.eos_id) or full):
-                finished.append(rid)
-        for rid in finished:
-            self._finish(self.active.pop(rid), now, "accepted")
-            self.pool.release(rid)
-        self.stats.decode_tokens += produced
-        return produced
+        return active_mask, budget_arr, eos_arr, temp_arr, topk_arr, seed_arr
+
+    def _decode_paged(self, now: float) -> int:
+        """Fused horizon decode: one jitted launch runs up to ``horizon``
+        steps for every active lane; the host syncs once to drain the
+        produced ``(tokens, emitted)`` horizon."""
+        with TraceAnnotation("engine.decode") as span:
+            with TraceAnnotation("engine.decode.prepare"):
+                budgets = {rid: req.max_new_tokens - len(req.output)
+                           for rid, req in self.active.items()}
+                # always launch `horizon` steps: the scan skips drained tail
+                # steps on-device (lax.cond), so num_steps stays one static
+                # value and the decode graph never retraces mid-run
+                n_eff = self.horizon
+                # allocate append blocks oldest-request-first; when the pool
+                # is exhausted the youngest actives are the ones preempted
+                victims = self.pool.ensure_append_blocks(
+                    sorted(self.active), horizon=n_eff, budgets=budgets)
+                if victims:
+                    self._preempt(victims)
+                if TraceAnnotation.is_enabled():
+                    span.set_metadata(**self._decode_args(n_eff,
+                                                          len(victims)))
+                if not self.active:
+                    return 0
+                mask, budget_arr, eos_arr, temps, top_ks, seeds = \
+                    self._lane_arrays(budgets)
+                # sampling arrays are only passed when some lane needs them,
+                # so an all-greedy engine runs the identical pre-sampling
+                # graph
+                sampled = any(r.temperature > 0
+                              for r in self.active.values())
+                extra = dict(temps=jnp.asarray(temps),
+                             top_ks=jnp.asarray(top_ks),
+                             seeds=jnp.asarray(seeds)) if sampled else {}
+                args = (self.pool.last_tokens_dev(), self.pool.positions(),
+                        self.pool.tables(), jnp.asarray(mask),
+                        jnp.asarray(budget_arr), jnp.asarray(eos_arr))
+            with TraceAnnotation("engine.decode.launch"):
+                toks, emitted, _, (tok_f, pos_f, _, _), self.pool.cache = \
+                    self._decode_multi_jit(
+                        self.params, self.pool.cache, *args,
+                        num_steps=n_eff, max_len=self.max_seq, **extra)
+            with TraceAnnotation("engine.decode.sync"):
+                toks_h = np.asarray(toks)    # the horizon's single host sync
+                em_h = np.asarray(emitted)
+            with TraceAnnotation("engine.decode.commit"):
+                self.stats.host_syncs += 1
+                self.stats.decode_syncs += 1
+                # the loop's final device state becomes the pool mirror —
+                # nothing is re-uploaded next launch; numpy mirrors updated
+                # below
+                self.pool.adopt_device("positions", pos_f)
+                self.pool.adopt_device("last_tokens", tok_f)
+                produced = 0
+                finished = []
+                for rid, req in list(self.active.items()):
+                    lane = self.pool.lane_of[rid]
+                    cnt = int(em_h[:, lane].sum())
+                    req.output.extend(int(t) for t in toks_h[:cnt, lane])
+                    produced += cnt
+                    self.pool.lengths[lane] += cnt
+                    self.pool.last_tokens[lane] = req.output[-1]
+                    full = int(self.pool.lengths[lane]) + 1 > self.max_seq
+                    if (len(req.output) >= req.max_new_tokens
+                            or (req.eos_id is not None
+                                and req.output[-1] == req.eos_id) or full):
+                        finished.append(rid)
+                for rid in finished:
+                    self._finish(self.active.pop(rid), now, "accepted")
+                    self.pool.release(rid)
+                self.stats.decode_tokens += produced
+            return produced
 
     def _decode_spec(self, now: float) -> int:
         """Fused speculative decode: each launch runs up to ``horizon``
         verify rounds; every round advances each lane by its accepted
         draft run + 1, so one host sync drains up to
         ``horizon * (spec_k + 1)`` tokens per lane."""
-        budgets = {rid: req.max_new_tokens - len(req.output)
-                   for rid, req in self.active.items()}
-        k = self.spec_k
-        # always launch `horizon` rounds: the scan skips exhausted tail
-        # rounds on-device (lax.cond), so num_steps stays one static value
-        # and the spec graph never retraces mid-run
-        n_eff = self.horizon
-        # each round may write KV up to spec_k slots past the emitted run,
-        # so pad the per-request budgets by spec_k for block reservation
-        victims = self.pool.ensure_append_blocks(
-            sorted(self.active), horizon=n_eff * (k + 1),
-            budgets={rid: b + k for rid, b in budgets.items()})
-        if victims:
-            self._preempt(victims)
-        if not self.active:
-            return 0
-        width = self.pool.n_lanes
-        active_mask = np.zeros(width, bool)
-        budget_arr = np.zeros(width, np.int32)
-        eos_arr = np.full(width, -1, np.int32)
-        temp_arr = np.zeros(width, np.float32)
-        topk_arr = np.zeros(width, np.int32)
-        seed_arr = np.zeros(width, np.int32)
-        for rid, req in self.active.items():
-            lane = self.pool.lane_of[rid]
-            active_mask[lane] = True
-            budget_arr[lane] = budgets[rid]
-            if req.eos_id is not None:
-                eos_arr[lane] = req.eos_id
-            temp_arr[lane] = req.temperature
-            topk_arr[lane] = req.top_k
-            seed_arr[lane] = self._req_seed(req)
-        toks, em, acc, (tok_f, pos_f, _, _), self.pool.cache, \
-            self.pool.draft_cache, hist_f = self._decode_spec_jit(
-                self.params, self.pool.cache, self._d_params,
-                self.pool.draft_cache, self.pool.hist_dev(),
-                self.pool.last_tokens_dev(), self.pool.positions(),
-                self.pool.tables(), jnp.asarray(active_mask),
-                jnp.asarray(budget_arr), jnp.asarray(eos_arr),
-                jnp.asarray(temp_arr), jnp.asarray(topk_arr),
-                jnp.asarray(seed_arr), num_steps=n_eff, spec_k=k,
-                max_len=self.max_seq, ngram=self.ngram)
-        toks_h = np.asarray(toks)       # (N, B, K+1) — the single sync
-        em_h = np.asarray(em)
-        acc_h = np.asarray(acc)         # (N, B) accepted drafts per round
-        self.stats.host_syncs += 1
-        self.stats.decode_syncs += 1
-        self.pool.adopt_device("positions", pos_f)
-        self.pool.adopt_device("last_tokens", tok_f)
-        self.pool.adopt_device("hist", hist_f)
-        produced = 0
-        finished = []
-        for rid, req in list(self.active.items()):
-            lane = self.pool.lane_of[rid]
-            em_l = em_h[:, lane, :]                          # (N, K+1)
-            # row-major boolean drain preserves round-then-slot order
-            new = [int(t) for t in toks_h[:, lane, :][em_l]]
-            cnt = len(new)
-            # a verify pass ran for this lane iff its slot 0 emitted
-            rounds = int(em_l[:, 0].sum())
-            self.stats.verify_passes += rounds
-            self.stats.draft_tokens += k * rounds
-            self.stats.accepted_tokens += int(
-                np.minimum(em_l.sum(axis=1), acc_h[:, lane]).sum())
-            if cnt:
-                base = int(self.pool.lengths[lane])
-                self.pool.token_hist[lane, base + 1: base + 1 + cnt] = new
-                req.output.extend(new)
-                produced += cnt
-                self.pool.lengths[lane] += cnt
-                self.pool.last_tokens[lane] = req.output[-1]
-            full = int(self.pool.lengths[lane]) + 1 > self.max_seq
-            if (len(req.output) >= req.max_new_tokens
-                    or (req.eos_id is not None
-                        and req.output[-1] == req.eos_id) or full):
-                finished.append(rid)
-        for rid in finished:
-            self._finish(self.active.pop(rid), now, "accepted")
-            self.pool.release(rid)
-        self.stats.decode_tokens += produced
-        return produced
+        with TraceAnnotation("engine.decode") as span:
+            with TraceAnnotation("engine.decode.prepare"):
+                budgets = {rid: req.max_new_tokens - len(req.output)
+                           for rid, req in self.active.items()}
+                k = self.spec_k
+                # always launch `horizon` rounds: the scan skips exhausted
+                # tail rounds on-device (lax.cond), so num_steps stays one
+                # static value and the spec graph never retraces mid-run
+                n_eff = self.horizon
+                # each round may write KV up to spec_k slots past the
+                # emitted run, so pad the per-request budgets by spec_k for
+                # block reservation
+                victims = self.pool.ensure_append_blocks(
+                    sorted(self.active), horizon=n_eff * (k + 1),
+                    budgets={rid: b + k for rid, b in budgets.items()})
+                if victims:
+                    self._preempt(victims)
+                if TraceAnnotation.is_enabled():
+                    span.set_metadata(**self._decode_args(n_eff,
+                                                          len(victims)))
+                if not self.active:
+                    return 0
+                lanes = tuple(jnp.asarray(a)
+                              for a in self._lane_arrays(budgets))
+                hist = self.pool.hist_dev()
+                state = (self.pool.last_tokens_dev(), self.pool.positions(),
+                         self.pool.tables())
+            with TraceAnnotation("engine.decode.launch"):
+                toks, em, acc, (tok_f, pos_f, _, _), self.pool.cache, \
+                    self.pool.draft_cache, hist_f = self._decode_spec_jit(
+                        self.params, self.pool.cache, self._d_params,
+                        self.pool.draft_cache, hist, *state, *lanes,
+                        num_steps=n_eff, spec_k=k, max_len=self.max_seq,
+                        ngram=self.ngram)
+            with TraceAnnotation("engine.decode.sync"):
+                toks_h = np.asarray(toks)     # (N, B, K+1) — the single sync
+                em_h = np.asarray(em)
+                acc_h = np.asarray(acc)       # (N, B) accepted drafts/round
+            with TraceAnnotation("engine.decode.commit"):
+                self.stats.host_syncs += 1
+                self.stats.decode_syncs += 1
+                self.pool.adopt_device("positions", pos_f)
+                self.pool.adopt_device("last_tokens", tok_f)
+                self.pool.adopt_device("hist", hist_f)
+                produced = 0
+                finished = []
+                for rid, req in list(self.active.items()):
+                    lane = self.pool.lane_of[rid]
+                    em_l = em_h[:, lane, :]                      # (N, K+1)
+                    # row-major boolean drain preserves round-then-slot
+                    # order
+                    new = [int(t) for t in toks_h[:, lane, :][em_l]]
+                    cnt = len(new)
+                    # a verify pass ran for this lane iff its slot 0 emitted
+                    rounds = int(em_l[:, 0].sum())
+                    self.stats.verify_passes += rounds
+                    self.stats.draft_tokens += k * rounds
+                    self.stats.accepted_tokens += int(
+                        np.minimum(em_l.sum(axis=1), acc_h[:, lane]).sum())
+                    if cnt:
+                        base = int(self.pool.lengths[lane])
+                        self.pool.token_hist[lane,
+                                             base + 1: base + 1 + cnt] = new
+                        req.output.extend(new)
+                        produced += cnt
+                        self.pool.lengths[lane] += cnt
+                        self.pool.last_tokens[lane] = req.output[-1]
+                    full = int(self.pool.lengths[lane]) + 1 > self.max_seq
+                    if (len(req.output) >= req.max_new_tokens
+                            or (req.eos_id is not None
+                                and req.output[-1] == req.eos_id) or full):
+                        finished.append(rid)
+                for rid in finished:
+                    self._finish(self.active.pop(rid), now, "accepted")
+                    self.pool.release(rid)
+                self.stats.decode_tokens += produced
+            return produced
 
     def _decode_slots(self, now: float) -> int:
-        lanes = {rid: self.pool.slot_of[rid] for rid in self.active}
-        width = self.pool.n_slots
-        tokens = [0] * width
-        for rid, req in self.active.items():
-            tokens[lanes[rid]] = req.output[-1]
-        positions = self.pool.positions()
-        logits, self.pool.cache = self._decode_jit(
-            self.params, self.pool.cache,
-            jnp.asarray(tokens, jnp.int32), positions)
-        if any(r.temperature > 0 for r in self.active.values()):
-            temps = np.zeros(width, np.float32)
-            tks = np.zeros(width, np.int32)
-            seeds = np.zeros(width, np.int32)
-            for rid, req in self.active.items():
-                ln = lanes[rid]
-                temps[ln] = req.temperature
-                tks[ln] = req.top_k
-                seeds[ln] = self._req_seed(req)
-            t = jnp.asarray(temps)
-            dist = sampling_dist(logits[:, : self.model.cfg.vocab_size],
-                                 t, jnp.asarray(tks))
-            keys = event_keys(lane_keys(jnp.asarray(seeds)),
-                              positions + 1, SALT_SAMPLE)
-            nxt = np.asarray(sample_from_dist(keys, dist, t <= 0.0))
-        else:
-            nxt = np.asarray(
-                jnp.argmax(logits[:, : self.model.cfg.vocab_size], axis=-1))
-        self.stats.host_syncs += 1
-        self.stats.decode_syncs += 1
-        produced = 0
-        finished = []
-        for rid, req in list(self.active.items()):
-            ln = lanes[rid]
-            tok = int(nxt[ln])
-            req.output.append(tok)
-            produced += 1
-            full = int(self.pool.lengths[ln]) + 1 >= self.max_seq
-            if (len(req.output) >= req.max_new_tokens
-                    or (req.eos_id is not None and tok == req.eos_id) or full):
-                finished.append(rid)
-        self.pool.advance(list(lanes.values()))
-        for rid in finished:
-            self._finish(self.active.pop(rid), now, "accepted")
-            self.pool.release(rid)
-        self.stats.decode_tokens += produced
-        return produced
+        with TraceAnnotation("engine.decode") as span:
+            with TraceAnnotation("engine.decode.prepare"):
+                if TraceAnnotation.is_enabled():
+                    span.set_metadata(**self._decode_args(1, 0))
+                lanes = {rid: self.pool.slot_of[rid] for rid in self.active}
+                width = self.pool.n_slots
+                tokens = [0] * width
+                for rid, req in self.active.items():
+                    tokens[lanes[rid]] = req.output[-1]
+                tokens = jnp.asarray(tokens, jnp.int32)
+                positions = self.pool.positions()
+                sampled = any(r.temperature > 0
+                              for r in self.active.values())
+                if sampled:
+                    temps = np.zeros(width, np.float32)
+                    tks = np.zeros(width, np.int32)
+                    seeds = np.zeros(width, np.int32)
+                    for rid, req in self.active.items():
+                        ln = lanes[rid]
+                        temps[ln] = req.temperature
+                        tks[ln] = req.top_k
+                        seeds[ln] = self._req_seed(req)
+                    t, tks, seeds = (jnp.asarray(temps), jnp.asarray(tks),
+                                     jnp.asarray(seeds))
+            with TraceAnnotation("engine.decode.launch"):
+                logits, self.pool.cache = self._decode_jit(
+                    self.params, self.pool.cache, tokens, positions)
+                v = self.model.cfg.vocab_size
+                if sampled:
+                    dist = sampling_dist(logits[:, :v], t, tks)
+                    keys = event_keys(lane_keys(seeds), positions + 1,
+                                      SALT_SAMPLE)
+                    nxt = sample_from_dist(keys, dist, t <= 0.0)
+                else:
+                    nxt = jnp.argmax(logits[:, :v], axis=-1)
+            with TraceAnnotation("engine.decode.sync"):
+                nxt = np.asarray(nxt)
+            with TraceAnnotation("engine.decode.commit"):
+                self.stats.host_syncs += 1
+                self.stats.decode_syncs += 1
+                produced = 0
+                finished = []
+                for rid, req in list(self.active.items()):
+                    ln = lanes[rid]
+                    tok = int(nxt[ln])
+                    req.output.append(tok)
+                    produced += 1
+                    full = int(self.pool.lengths[ln]) + 1 >= self.max_seq
+                    if (len(req.output) >= req.max_new_tokens
+                            or (req.eos_id is not None and tok == req.eos_id)
+                            or full):
+                        finished.append(rid)
+                self.pool.advance(list(lanes.values()))
+                for rid in finished:
+                    self._finish(self.active.pop(rid), now, "accepted")
+                    self.pool.release(rid)
+                self.stats.decode_tokens += produced
+            return produced
 
     def step(self, now: float | None = None) -> int:
         """One scheduler iteration: admit, advance chunked prefills, then
@@ -1103,26 +1228,33 @@ class Engine:
         now = now if now is not None else t0
         if self.offline:
             return 0
-        self._release_delayed(now)
-        self._expire_deadlines(now)
-        self._quarantine_scan(now)
-        self._admit(now)
-        prefilled = self._prefill_tick(now) \
-            if self.paged and self.prefill_chunk else 0
-        produced = 0
-        if self.active:
-            if self._spec_on:
-                produced = self._decode_spec(now)
-            elif self.paged:
-                produced = self._decode_paged(now)
-            else:
-                produced = self._decode_slots(now)
-        if produced or prefilled:
-            # simulated frequency knob: a capped clock stretches wall
-            # time; a stuck-slow fault stretches it further
-            self.stats.record_step((time.perf_counter() - t0)
-                                   * self.slow_factor
-                                   / max(self.knobs.freq_scale, 1e-3))
+        self._step_num += 1
+        with StepTraceAnnotation("engine.step",
+                                 step_num=self._step_num) as span:
+            if TraceAnnotation.is_enabled():
+                span.set_metadata(**self._step_args())
+            if self._delayed or self._has_deadlines or self._guard_armed:
+                with TraceAnnotation("engine.housekeeping"):
+                    self._release_delayed(now)
+                    self._expire_deadlines(now)
+                    self._quarantine_scan(now)
+            self._admit(now)
+            prefilled = self._prefill_tick(now) \
+                if self.paged and self.prefill_chunk else 0
+            produced = 0
+            if self.active:
+                if self._spec_on:
+                    produced = self._decode_spec(now)
+                elif self.paged:
+                    produced = self._decode_paged(now)
+                else:
+                    produced = self._decode_slots(now)
+            if produced or prefilled:
+                # simulated frequency knob: a capped clock stretches wall
+                # time; a stuck-slow fault stretches it further
+                self.stats.record_step((time.perf_counter() - t0)
+                                       * self.slow_factor
+                                       / max(self.knobs.freq_scale, 1e-3))
         return produced
 
     def run(self, *, max_steps: int = 10_000) -> EngineStats:

@@ -32,9 +32,16 @@ class Request:
     deadline_ms: float | None = None
     max_retries: int = 3
 
-    # filled during serving
+    # filled during serving.  first_token_s / finish_s are the ``now``
+    # the caller passed to the Engine.step() that produced the first token
+    # / finished the request, i.e. the start of that step on the caller's
+    # clock, not the time the token reached the host.  queued_ns is the
+    # host's monotonic clock (time.perf_counter_ns) when the request last
+    # entered the admission queue: at submit(), and again when a
+    # preemption, retry or crash recovery puts it back.
     first_token_s: float | None = None
     finish_s: float | None = None
+    queued_ns: int | None = None
     output: list = field(default_factory=list)
     retries: int = 0
     outcome: str | None = None   # one of OUTCOMES once terminal
