@@ -559,12 +559,13 @@ def _swa_decode(p, q, k_new, v_new, cache, positions, cfg, plan, kv_idx, scale):
     return plan.constrain(out, ("batch", "embed_act"), cfg), {"k": k_c, "v": v_c}
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, positions, *,
+def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, *,
                     scale: float, kv_idx: jax.Array) -> jax.Array:
     """Decode attention over a paged KV pool (XLA gather path).
 
-    q: (B, H, hd); k_pool/v_pool: (n_blocks, bs, K, hd);
-    block_tables: (B, T) physical block ids; positions: (B,).
+    q: (B, H, hd); k_pool/v_pool: the layer-stacked (L, n_blocks, bs, K,
+    hd), read at ``layer``; block_tables: (B, T) physical block ids;
+    positions: (B,).
 
     The per-sequence logical view is gathered from the pool through the
     block table, then masked by position.  This XLA gather is what the
@@ -574,10 +575,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, *,
     against this path and compile-tested for TPU, but not called here.
     """
     B, H = q.shape[:2]
-    bs, K = k_pool.shape[1], k_pool.shape[2]
+    bs, K = k_pool.shape[2], k_pool.shape[3]
     T = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(B, T * bs, K, -1)
-    v = v_pool[block_tables].reshape(B, T * bs, K, -1)
+    k = k_pool[layer, block_tables].reshape(B, T * bs, K, -1)
+    v = v_pool[layer, block_tables].reshape(B, T * bs, K, -1)
     ke = _expand_kv(k, kv_idx, H)
     ve = _expand_kv(v, kv_idx, H)
     s = jnp.einsum("bhd,bshd->bhs", q, ke,
@@ -590,13 +591,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, *,
     return o.astype(q.dtype)
 
 
-def paged_prefill_attention(q, k_pool, v_pool, block_tables, starts, *,
-                            scale: float, kv_idx: jax.Array) -> jax.Array:
+def paged_prefill_attention(q, k_pool, v_pool, layer, block_tables, starts,
+                            *, scale: float, kv_idx: jax.Array) -> jax.Array:
     """Chunk-prefill attention over a paged KV pool (XLA gather path).
 
     q: (B, C, H, hd) — a chunk of C query tokens per sequence whose first
-    token sits at absolute position ``starts[b]``; k_pool/v_pool:
-    (n_blocks, bs, K, hd); block_tables: (B, T).  The chunk's own KV must
+    token sits at absolute position ``starts[b]``; k_pool/v_pool: the
+    layer-stacked (L, n_blocks, bs, K, hd), read at ``layer``;
+    block_tables: (B, T).  The chunk's own KV must
     already be written into the pool (see ``gqa_prefill_paged``), so one
     gather serves both the cached context and the within-chunk causal
     part: position kpos is visible to chunk token c iff
@@ -606,10 +608,10 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, starts, *,
     tested and compile-tested for TPU but not called here.
     """
     B, C, H = q.shape[:3]
-    bs, K = k_pool.shape[1], k_pool.shape[2]
+    bs, K = k_pool.shape[2], k_pool.shape[3]
     T = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(B, T * bs, K, -1)
-    v = v_pool[block_tables].reshape(B, T * bs, K, -1)
+    k = k_pool[layer, block_tables].reshape(B, T * bs, K, -1)
+    v = v_pool[layer, block_tables].reshape(B, T * bs, K, -1)
     ke = _expand_kv(k, kv_idx, H)
     ve = _expand_kv(v, kv_idx, H)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, ke,
@@ -623,8 +625,16 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, starts, *,
     return o.astype(q.dtype)
 
 
+def _pool_index(layer, *idx):
+    """Index into a pool stripe: one layer's ``(nb_loc, bs, K, hd)`` when
+    ``layer`` is None, else the layer-stacked ``(L, nb_loc, bs, K, hd)``
+    at ``layer`` — one gather or scatter into the stacked buffer, never a
+    slice of the layer first."""
+    return idx if layer is None else (layer, *idx)
+
+
 def _paged_decode_core(axis, q, k_pool, v_pool, block_tables, positions,
-                       k_new, v_new, *, scale, kv_idx):
+                       k_new, v_new, layer=None, *, scale, kv_idx):
     """Per-shard paged decode on a block-stripe of the pool, LSE-combined.
 
     Rank r owns physical blocks ``[r*nb_loc, (r+1)*nb_loc)``: the new
@@ -633,11 +643,12 @@ def _paged_decode_core(axis, q, k_pool, v_pool, block_tables, positions,
     entries, and the softmax merges across ranks via the same
     max/sum-reduce (pmax/psum) idiom as ``_flash_decode_core``.
 
-    q: (B, H, hd); k_pool/v_pool: (nb_loc, bs, K, hd) local stripe;
-    block_tables: (B, T) *global* block ids; k_new/v_new: (B, K, hd).
+    q: (B, H, hd); k_pool/v_pool: (nb_loc, bs, K, hd) local stripe, or
+    the layer-stacked (L, nb_loc, bs, K, hd) at ``layer``; block_tables:
+    (B, T) *global* block ids; k_new/v_new: (B, K, hd).
     """
     B, H = q.shape[:2]
-    nb_loc, bs, K = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    nb_loc, bs, K = k_pool.shape[-4:-1]
     off = (jax.lax.axis_index(axis) * nb_loc) if axis is not None else 0
     blk = jnp.take_along_axis(block_tables, (positions // bs)[:, None],
                               axis=1)[:, 0]
@@ -646,16 +657,15 @@ def _paged_decode_core(axis, q, k_pool, v_pool, block_tables, positions,
     owned = (local_b >= 0) & (local_b < nb_loc)
     safe_b = jnp.where(owned, local_b, nb_loc)     # OOB on unowned -> dropped
     with jax.named_scope("kv_append"):
-        k_pool = k_pool.at[safe_b, o_in_b].set(k_new.astype(k_pool.dtype),
-                                               mode="drop")
-        v_pool = v_pool.at[safe_b, o_in_b].set(v_new.astype(v_pool.dtype),
-                                               mode="drop")
+        at = _pool_index(layer, safe_b, o_in_b)
+        k_pool = k_pool.at[at].set(k_new.astype(k_pool.dtype), mode="drop")
+        v_pool = v_pool.at[at].set(v_new.astype(v_pool.dtype), mode="drop")
     T = block_tables.shape[1]
     local_t = block_tables - off
     t_owned = (local_t >= 0) & (local_t < nb_loc)  # (B, T)
     safe_t = jnp.clip(local_t, 0, nb_loc - 1)
-    k = k_pool[safe_t].reshape(B, T * bs, K, -1)
-    v = v_pool[safe_t].reshape(B, T * bs, K, -1)
+    k = k_pool[_pool_index(layer, safe_t)].reshape(B, T * bs, K, -1)
+    v = v_pool[_pool_index(layer, safe_t)].reshape(B, T * bs, K, -1)
     ke = _expand_kv(k, kv_idx, H)
     ve = _expand_kv(v, kv_idx, H)
     s = jnp.einsum("bhd,bshd->bhs", q, ke,
@@ -678,15 +688,16 @@ def _paged_decode_core(axis, q, k_pool, v_pool, block_tables, positions,
 
 
 def _paged_prefill_core(axis, q, k_pool, v_pool, block_tables, starts,
-                        lengths, k, v, *, scale, kv_idx):
+                        lengths, k, v, layer=None, *, scale, kv_idx):
     """Per-shard chunk prefill on a block-stripe of the pool, LSE-combined.
 
-    q: (B, C, H, hd); k/v: (B, C, K, hd) the chunk's new KV (rope applied,
-    real heads); the scatter-then-gather ordering inside the core keeps
-    within-chunk causal attention exact on the rank that owns each block.
+    q: (B, C, H, hd); k_pool/v_pool as in ``_paged_decode_core``; k/v:
+    (B, C, K, hd) the chunk's new KV (rope applied, real heads); the
+    scatter-then-gather ordering inside the core keeps within-chunk causal
+    attention exact on the rank that owns each block.
     """
     B, C, H = q.shape[:3]
-    nb_loc, bs, K = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    nb_loc, bs, K = k_pool.shape[-4:-1]
     off = (jax.lax.axis_index(axis) * nb_loc) if axis is not None else 0
     positions = starts[:, None] + jnp.arange(C)[None, :]
     valid = jnp.arange(C)[None, :] < lengths[:, None]
@@ -698,16 +709,17 @@ def _paged_prefill_core(axis, q, k_pool, v_pool, block_tables, starts,
     owned_w = valid & (local_b >= 0) & (local_b < nb_loc)
     safe_b = jnp.where(owned_w, local_b, nb_loc)   # OOB on unowned -> dropped
     with jax.named_scope("kv_append"):
-        k_pool = k_pool.at[safe_b.reshape(-1), o_in_b.reshape(-1)].set(
+        at = _pool_index(layer, safe_b.reshape(-1), o_in_b.reshape(-1))
+        k_pool = k_pool.at[at].set(
             k.reshape(B * C, K, -1).astype(k_pool.dtype), mode="drop")
-        v_pool = v_pool.at[safe_b.reshape(-1), o_in_b.reshape(-1)].set(
+        v_pool = v_pool.at[at].set(
             v.reshape(B * C, K, -1).astype(v_pool.dtype), mode="drop")
     T = block_tables.shape[1]
     local_t = block_tables - off
     t_owned = (local_t >= 0) & (local_t < nb_loc)
     safe_t = jnp.clip(local_t, 0, nb_loc - 1)
-    kk = k_pool[safe_t].reshape(B, T * bs, K, -1)
-    vv = v_pool[safe_t].reshape(B, T * bs, K, -1)
+    kk = k_pool[_pool_index(layer, safe_t)].reshape(B, T * bs, K, -1)
+    vv = v_pool[_pool_index(layer, safe_t)].reshape(B, T * bs, K, -1)
     ke = _expand_kv(kk, kv_idx, H)
     ve = _expand_kv(vv, kv_idx, H)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, ke,
@@ -729,7 +741,7 @@ def _paged_prefill_core(axis, q, k_pool, v_pool, block_tables, starts,
     return o.astype(q.dtype), k_pool, v_pool
 
 
-def gqa_prefill_paged(p: Params, x: jax.Array, cache: Params,
+def gqa_prefill_paged(p: Params, x: jax.Array, cache: Params, layer,
                       starts: jax.Array, lengths: jax.Array,
                       block_tables: jax.Array, cfg: ArchConfig,
                       plan: ShardPlan):
@@ -737,9 +749,12 @@ def gqa_prefill_paged(p: Params, x: jax.Array, cache: Params,
     tokens, scatter its KV into the owned blocks, then attend through the
     block table (cached context + within-chunk causal in one gather).
 
-    x: (B, C, d); starts: (B,) absolute position of x[:, 0]; lengths: (B,)
-    valid tokens per row (ragged tails).  Invalid positions are routed to
-    the reserved parking block 0, whose contents are never read unmasked.
+    x: (B, C, d); cache: the layer-stacked pool ``{"k", "v"}`` (leaves
+    (L, n_blocks, bs, K, hd)), written and read at ``layer``; starts: (B,)
+    absolute position of x[:, 0]; lengths: (B,) valid tokens per row
+    (ragged tails).  Invalid positions are routed to the reserved parking
+    block 0, whose contents are never read unmasked.  Returns the whole
+    updated stacked pool.
     """
     dt = plan.compute_dtype
     h_pad = plan.h_pad(cfg)
@@ -761,45 +776,49 @@ def gqa_prefill_paged(p: Params, x: jax.Array, cache: Params,
     if plan.paged_pool_sharded(cfg):
         dp = plan.dp_axes if plan.dp_axes else None
         tp = plan.tp_axis
-        in_specs = (P(dp, None, None, None), P(tp, None, None, None),
-                    P(tp, None, None, None), P(dp, None), P(dp), P(dp),
-                    P(dp, None, None, None), P(dp, None, None, None))
-        out_specs = (P(dp, None, None, None), P(tp, None, None, None),
-                     P(tp, None, None, None))
+        pool = P(None, tp, None, None, None)
+        in_specs = (P(dp, None, None, None), pool, pool, P(dp, None), P(dp),
+                    P(dp), P(dp, None, None, None), P(dp, None, None, None),
+                    P())
+        out_specs = (P(dp, None, None, None), pool, pool)
         o, k_c, v_c = shard_map_or_call(
             plan,
             lambda ax, *a: _paged_prefill_core(ax, *a, scale=scale, kv_idx=idx),
             in_specs, out_specs, q, cache["k"], cache["v"], block_tables,
-            starts, lengths, k, v)
+            starts, lengths, k, v, layer)
     else:
-        bs = cache["k"].shape[1]
-        K = cache["k"].shape[2]
+        bs, K = cache["k"].shape[2], cache["k"].shape[3]
         valid = jnp.arange(C)[None, :] < lengths[:, None]
         safe_pos = jnp.where(valid, positions, 0)
         blk = jnp.take_along_axis(block_tables, safe_pos // bs, axis=1)
         blk = jnp.where(valid, blk, 0)
         off = jnp.where(valid, safe_pos % bs, 0)
+        at = (layer, blk.reshape(-1), off.reshape(-1))
         with jax.named_scope("kv_append"):
-            k_c = cache["k"].at[blk.reshape(-1), off.reshape(-1)].set(
+            k_c = cache["k"].at[at].set(
                 k.reshape(B * C, K, -1).astype(cache["k"].dtype))
-            v_c = cache["v"].at[blk.reshape(-1), off.reshape(-1)].set(
+            v_c = cache["v"].at[at].set(
                 v.reshape(B * C, K, -1).astype(cache["v"].dtype))
-        o = paged_prefill_attention(q, k_c, v_c, block_tables, starts,
+        o = paged_prefill_attention(q, k_c, v_c, layer, block_tables, starts,
                                     scale=scale, kv_idx=idx)
     out = jnp.einsum("bshk,hkd->bsd", o, p["w_o"].astype(dt))
     return plan.constrain(out, ("batch", "seq", "embed_act"), cfg), \
         {"k": k_c, "v": v_c}
 
 
-def gqa_decode_paged(p: Params, x: jax.Array, cache: Params,
+def gqa_decode_paged(p: Params, x: jax.Array, cache: Params, layer,
                      positions: jax.Array, block_tables: jax.Array,
                      cfg: ArchConfig, plan: ShardPlan):
     """Paged-pool decode step: write the new token's KV into its block,
     attend through the block table.  x: (B, d) -> (out (B, d), new cache).
 
-    The write touches exactly one (block, offset) slot per sequence —
-    O(active sequences), independent of pool size — and under jit with a
-    donated cache XLA updates the pool in place.
+    cache is the layer-stacked pool ``{"k", "v"}`` (leaves (L, n_blocks,
+    bs, K, hd)); the write and the gather address it at ``layer``.  The
+    write touches exactly one (layer, block, offset) slot per sequence —
+    O(active sequences), independent of pool size.  It lands in place
+    only because the caller's layer scan carries the stacked pool (see
+    ``Model.decode_step_paged``) and jit donates it: scanning the pool as
+    per-layer slices would copy it whatever the donation.
     """
     dt = plan.compute_dtype
     h_pad = plan.h_pad(cfg)
@@ -809,25 +828,26 @@ def gqa_decode_paged(p: Params, x: jax.Array, cache: Params,
     if plan.paged_pool_sharded(cfg):
         dp = plan.dp_axes if plan.dp_axes else None
         tp = plan.tp_axis
-        in_specs = (P(dp, None, None), P(tp, None, None, None),
-                    P(tp, None, None, None), P(dp, None), P(dp),
-                    P(dp, None, None), P(dp, None, None))
-        out_specs = (P(dp, None, None), P(tp, None, None, None),
-                     P(tp, None, None, None))
+        pool = P(None, tp, None, None, None)
+        in_specs = (P(dp, None, None), pool, pool, P(dp, None), P(dp),
+                    P(dp, None, None), P(dp, None, None), P())
+        out_specs = (P(dp, None, None), pool, pool)
         o, k_c, v_c = shard_map_or_call(
             plan,
             lambda ax, *a: _paged_decode_core(ax, *a, scale=scale, kv_idx=idx),
             in_specs, out_specs, q, cache["k"], cache["v"], block_tables,
-            positions, k_new, v_new)
+            positions, k_new, v_new, layer)
     else:
-        bs = cache["k"].shape[1]
+        bs = cache["k"].shape[2]
         blk = jnp.take_along_axis(block_tables, (positions // bs)[:, None],
                                   axis=1)[:, 0]
         off = positions % bs
         with jax.named_scope("kv_append"):
-            k_c = cache["k"].at[blk, off].set(k_new.astype(cache["k"].dtype))
-            v_c = cache["v"].at[blk, off].set(v_new.astype(cache["v"].dtype))
-        o = paged_attention(q, k_c, v_c, block_tables, positions,
+            k_c = cache["k"].at[layer, blk, off].set(
+                k_new.astype(cache["k"].dtype))
+            v_c = cache["v"].at[layer, blk, off].set(
+                v_new.astype(cache["v"].dtype))
+        o = paged_attention(q, k_c, v_c, layer, block_tables, positions,
                             scale=scale, kv_idx=idx)
     out = jnp.einsum("bhk,hkd->bd", o, p["w_o"].astype(dt))
     return plan.constrain(out, ("batch", "embed_act"), cfg), {"k": k_c, "v": v_c}

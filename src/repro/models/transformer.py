@@ -205,21 +205,23 @@ def block_decode(x, lp: Params, lc: Params, positions, cfg: ArchConfig,
     return x, new_cache
 
 
-def block_prefill_paged(x, lp: Params, lc: Params, starts, lengths,
-                        block_tables, cfg: ArchConfig, plan: ShardPlan):
+def block_prefill_paged(x, lp: Params, cache: Params, layer, starts,
+                        lengths, block_tables, cfg: ArchConfig,
+                        plan: ShardPlan):
     """Chunked-prefill variant of ``block_forward`` over the paged pool.
 
     x: (B, C, d) — one chunk of C prompt tokens per row starting at
-    absolute position ``starts[b]``; lc holds this layer's slice of the
-    global block pool.  The attention scatter/gather goes through the
-    per-sequence block table, so the chunk sees all previously written
-    context (earlier chunks, shared prefix blocks) plus itself causally.
+    absolute position ``starts[b]``; cache is the whole layer-stacked
+    pool and ``layer`` this block's index into it.  The attention
+    scatter/gather goes through the per-sequence block table, so the
+    chunk sees all previously written context (earlier chunks, shared
+    prefix blocks) plus itself causally.
     """
     with jax.named_scope("attention"):
         h = _norm(x, lp["norm1"], cfg)
         attn_out, attn_cache = A.gqa_prefill_paged(
-            lp["attn"], h, lc["attn"], starts, lengths, block_tables, cfg,
-            plan)
+            lp["attn"], h, cache["attn"], layer, starts, lengths,
+            block_tables, cfg, plan)
         x = x + attn_out
     with jax.named_scope("mlp"):
         h = _norm(x, lp["norm2"], cfg)
@@ -236,17 +238,19 @@ def block_prefill_paged(x, lp: Params, lc: Params, starts, lengths,
     return x, {"attn": attn_cache}
 
 
-def block_decode_paged(x, lp: Params, lc: Params, positions, block_tables,
-                       cfg: ArchConfig, plan: ShardPlan):
+def block_decode_paged(x, lp: Params, cache: Params, layer, positions,
+                       block_tables, cfg: ArchConfig, plan: ShardPlan):
     """Paged-pool variant of ``block_decode`` (plain-GQA families only).
 
-    lc holds this layer's slice of the global block pool; the attention
-    write/gather goes through the per-sequence block table.
+    cache is the whole layer-stacked pool and ``layer`` this block's index
+    into it; the attention write/gather goes through the per-sequence
+    block table.
     """
     with jax.named_scope("attention"):
         h = _norm(x, lp["norm1"], cfg)
         attn_out, attn_cache = A.gqa_decode_paged(
-            lp["attn"], h, lc["attn"], positions, block_tables, cfg, plan)
+            lp["attn"], h, cache["attn"], layer, positions, block_tables,
+            cfg, plan)
         x = x + attn_out
     with jax.named_scope("mlp"):
         h = _norm(x, lp["norm2"], cfg)
@@ -687,19 +691,27 @@ class Model:
     def decode_step_paged(self, params, cache, tokens, positions,
                           block_tables):
         """One token per lane over the paged pool.  tokens/positions: (B,);
-        block_tables: (B, T) physical block ids per lane."""
+        block_tables: (B, T) physical block ids per lane.
+
+        The layer scan carries the whole stacked pool and hands each layer
+        its index, not a slice: every layer scatters into and gathers from
+        the one carried buffer, which the donated argument then aliases.
+        (Scanning over the pool as ``xs``/``ys`` instead slices each layer
+        out and restacks it into a new buffer, copying the pool per call.)
+        """
         cfg, plan = self.cfg, self.plan
         x = embed_lookup(params["embed"], tokens, cfg, plan)
         if cfg.embed_scale:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), plan.compute_dtype)
 
-        def body(x, inp):
-            lp, lc = inp
-            x, new_lc = block_decode_paged(x, lp, lc, positions, block_tables,
-                                           cfg, plan)
-            return x, new_lc
+        def body(carry, inp):
+            x, cache = carry
+            lp, layer = inp
+            return block_decode_paged(x, lp, cache, layer, positions,
+                                      block_tables, cfg, plan), None
 
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+        (x, new_cache), _ = jax.lax.scan(
+            body, (x, cache), (params["layers"], jnp.arange(cfg.num_layers)))
         with jax.named_scope("head"):
             x = _norm(x, params["final_norm"], cfg)
             logits = self._head(params, x)
@@ -972,18 +984,20 @@ class Model:
         Returns (logits at each row's last valid chunk token (B, V_pad),
         cache).  Rows admitted mid-way through a longer prompt simply call
         this again with ``starts`` advanced; decode TBT is never blocked
-        for longer than one chunk.
+        for longer than one chunk.  The layer scan carries the stacked pool
+        as ``decode_step_paged`` does.
         """
         cfg, plan = self.cfg, self.plan
         x = self._embed_inputs(params, tokens)
 
-        def body(x, inp):
-            lp, lc = inp
-            x, new_lc = block_prefill_paged(x, lp, lc, starts, lengths,
-                                            block_tables, cfg, plan)
-            return x, new_lc
+        def body(carry, inp):
+            x, cache = carry
+            lp, layer = inp
+            return block_prefill_paged(x, lp, cache, layer, starts, lengths,
+                                       block_tables, cfg, plan), None
 
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+        (x, new_cache), _ = jax.lax.scan(
+            body, (x, cache), (params["layers"], jnp.arange(cfg.num_layers)))
         with jax.named_scope("head"):
             x = _norm(x, params["final_norm"], cfg)
             last = jnp.take_along_axis(
