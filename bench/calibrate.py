@@ -36,7 +36,7 @@ def reading(cell, seed: int, seconds: float) -> dict:
     eng = H.build_engine(cell.config, seed)
     H.warm_up(eng, cell.config, cell.mix, seed)
     setup = time.perf_counter() - t0
-    sched = generate(cell.mix, vocab=cell.config["vocab_size"], seed=seed,
+    sched = generate(cell.mix, vocab=H.vocab(cell.config), seed=seed,
                      seconds=seconds, rate_per_s=cell.params.get("rate_per_s"))
     run = H.drive(eng, sched, seconds)
     seqs = H.sample_for_check(run, cell.params["check"]["requests"], seed)

@@ -7,9 +7,19 @@ its own load, service limits and correctness limit in
 ``bench/cells/<cell>.json``.  Per-layer metrics are readers under
 ``bench/metrics/<metric>.py``.  Nothing here names a cell.
 
+A configuration names its model family (``"family"``, ``dense_gqa``
+where absent), and ``bench/families/<family>.py`` holds all that is
+particular to it: its sizes (``dims``, with the vocabulary ``v`` the
+traffic draws from), its seeded tensors (``weights``), the program's
+``ArchConfig`` (``arch``) and parameter tree (``to_program``), its
+float32 ``Reference`` and its FLOP and byte counts (``decode_step``,
+``prefill``, ``kv_bytes_per_token``).  Nothing here names a family's
+tensor or architecture field.
+
 The program under test is built through ``EngineSpec.build()`` and driven
-through ``Engine.step()``; its weights are made by ``bench/weights.py``
-from the seed, in the program's own parameter layout.
+through ``Engine.step()``; its weights are made from the seed
+(``bench/weights.py``), in one jitted call into the program's own
+parameter layout.
 """
 from __future__ import annotations
 
@@ -32,6 +42,8 @@ from bench import weights as W
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
+FAMILY_DIR = BENCH_DIR / "families"
+DEFAULT_FAMILY = "dense_gqa"
 
 
 # ---------------------------------------------------------------------------
@@ -63,6 +75,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     w = cells[name]
     cfgs = {c["name"]: c for c in bench["configs"]}
     config = _json(root / cfgs[w["config"]]["file"])
+    family(config)          # an unknown family fails here, before set-up
 
     def mine(m):
         return "workloads" not in m or name in m["workloads"]
@@ -74,14 +87,34 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
                 per_layer=[m for m in bench["per_layer"] if mine(m)])
 
 
-def metric_reader(name: str):
-    """``read(run) -> float | None`` of ``bench/metrics/<name>.py``."""
-    path = BENCH_DIR / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+def _load(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"),
+                                                  path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """``read(run) -> float | None`` of ``bench/metrics/<name>.py``."""
+    return _load(BENCH_DIR / "metrics" / f"{name}.py",
+                 f"bench_metric_{name}").read
+
+
+def family(config: dict):
+    """The module ``bench/families/<family>.py`` of a configuration."""
+    name = config.get("family", DEFAULT_FAMILY)
+    path = FAMILY_DIR / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(
+            f"{config.get('name')}: no model family {name!r}; families "
+            f"present: {sorted(p.stem for p in FAMILY_DIR.glob('*.py'))}")
+    return _load(path, f"bench_family_{name}")
+
+
+def vocab(config: dict) -> int:
+    """The vocabulary a configuration's traffic draws its token ids from."""
+    return family(config).dims(config)["v"]
 
 
 # ---------------------------------------------------------------------------
@@ -130,58 +163,21 @@ def memory_peak_bytes(chips: int) -> int | None:
 # ---------------------------------------------------------------------------
 
 def arch_for(config: dict):
-    """The program's ``ArchConfig`` for a configuration file: the
-    registered model with the file's sizes, checked to be the plain
-    dense GQA decoder the reference computes."""
+    """The program's ``ArchConfig`` for a configuration file, as its
+    family makes it from the registered model ``config["model"]``."""
     from repro.configs import get_config
-    dm = W.dims(config)
-    arch = get_config(config["model"]).replace(
-        num_layers=dm["layers"], d_model=dm["d"], n_heads=dm["h"],
-        n_kv_heads=dm["k"], head_dim=dm["hd"], d_ff=dm["f"],
-        vocab_size=dm["v"], qk_norm=dm["qk_norm"],
-        tie_embeddings=dm["tied"], rope_theta=dm["theta"])
-    want = dict(attn_kind="gqa", family="dense", norm_kind="rms",
-                norm_plus_one=False, embed_scale=False, activation="silu",
-                mlp_kind="glu", n_experts=0, causal=True,
-                input_kind="tokens")
-    bad = {k: (getattr(arch, k), v) for k, v in want.items()
-           if getattr(arch, k) != v}
-    if bad:
-        raise ValueError(f"{config['name']}: the program's {arch.name} is "
-                         f"not the decoder bench/reference.py computes: {bad}")
-    return arch
+    return family(config).arch(config, get_config(config["model"]))
 
 
 def program_params(model, config: dict, seed: int):
     """The benchmark's weights for ``seed`` in the program's parameter
     layout and shardings, made on the device in one jitted call."""
     import jax
-    import jax.numpy as jnp
-    dm = W.dims(config)
-    n, d, h, k, hd = dm["layers"], dm["d"], dm["h"], dm["k"], dm["hd"]
-    v_pad = model.plan.v_pad(model.cfg)
+    fam = family(config)
+    dm = fam.dims(config)
 
     def make(words):
-        key = W.seed_key(words)
-        lay = W.all_layers(key, dm)
-        out = W.outer(key, dm)
-        attn = {"w_q": lay["wq"].reshape(n, d, h, hd),
-                "w_k": lay["wk"].reshape(n, d, k, hd),
-                "w_v": lay["wv"].reshape(n, d, k, hd),
-                "w_o": lay["wo"].reshape(n, h, hd, d)}
-        if dm["qk_norm"]:
-            attn["q_norm"], attn["k_norm"] = lay["q_norm"], lay["k_norm"]
-        p = {"embed": jnp.pad(out["embed"], ((0, v_pad - dm["v"]), (0, 0))),
-             "layers": {"norm1": {"scale": lay["norm1"]},
-                        "norm2": {"scale": lay["norm2"]},
-                        "attn": attn,
-                        "mlp": {"w_gate": lay["w_gate"], "w_up": lay["w_up"],
-                                "w_down": lay["w_down"]}},
-             "final_norm": {"scale": out["final_norm"]}}
-        if not dm["tied"]:
-            p["unembed"] = jnp.pad(out["lm_head"],
-                                   ((0, 0), (0, v_pad - dm["v"])))
-        return p
+        return fam.to_program(fam.weights(W.seed_key(words), dm), dm, model)
 
     words = W.seed_words(seed)
     want = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
@@ -190,8 +186,8 @@ def program_params(model, config: dict, seed: int):
             or jax.tree.leaves(jax.tree.map(
                 lambda a, b: (a.shape, a.dtype) != (b.shape, b.dtype),
                 want, got)).count(True)):
-        raise ValueError("the program's parameter layout is not the one "
-                         "bench/harness.py writes the weights into")
+        raise ValueError(f"the program's parameter layout is not the one "
+                         f"{fam.__file__} writes the weights into")
     shardings = model.param_shardings()
     kw = {} if model.plan.mesh is None else {"out_shardings": shardings}
     return jax.jit(make, **kw)(words)
@@ -243,7 +239,7 @@ def warm_up(eng, config: dict, mix: dict, seed: int) -> int:
     rows = [1]
     while rows[-1] * 2 <= e["n_slots"]:
         rows.append(rows[-1] * 2)
-    vocab = W.dims(config)["v"]
+    v = vocab(config)
     rng = np.random.default_rng([int(seed), 0x5752])
     served = 0
     for temperature, top_k in sampling_modes(mix):
@@ -251,7 +247,7 @@ def warm_up(eng, config: dict, mix: dict, seed: int) -> int:
             for c in lengths:
                 for _ in range(b):
                     eng.submit(Request(
-                        prompt=rng.integers(0, vocab, c).tolist(),
+                        prompt=rng.integers(0, v, c).tolist(),
                         max_new_tokens=2, temperature=temperature,
                         top_k=top_k))
                 drain(eng)
@@ -519,11 +515,11 @@ def sample_for_check(run: dict, k: int, seed: int) -> list:
 def check(config: dict, mix: dict, seqs: list, seed: int, *,
           limit: float | None, control: bool = False,
           batch: int = 8) -> dict:
-    """Compare served tokens with the float32 reference: the widest gap by
-    which a served token's logit lies below the reference's best."""
-    from bench.reference import Reference
+    """Compare served tokens with the family's float32 reference: the
+    widest gap by which a served token's logit lies below the reference's
+    best."""
     e = config["engine"]
-    ref = Reference(config, seed)
+    ref = family(config).Reference(config, seed)
     gaps = []
     for i in range(0, len(seqs), batch):
         part = seqs[i:i + batch]

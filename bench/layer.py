@@ -2,9 +2,11 @@
 ``bench/metrics/``.  Each returns a number, or None when the run holds
 nothing to read (the harness then leaves the metric out).
 
-``run`` carries: ``config`` (the configuration file), ``chips``,
-``peak`` (``bench/peaks.py``), ``trace`` (``bench/trace.Trace``) and
-``window`` (its traced stretch, ns), ``recorder`` (``harness.Recorder``:
+``run`` carries: ``config`` (the configuration file, whose model family,
+``bench/families/<family>.py``, counts the FLOPs and bytes of the work
+the run recorded), ``chips``, ``peak`` (``bench/peaks.py``), ``trace``
+(``bench/trace.Trace``) and ``window`` (its traced stretch, ns),
+``recorder`` (``harness.Recorder``:
 decode launches, finished prefills and step times while tracing),
 ``counters`` (engine counters at the window's start and end) and
 ``admitted_prompt_tokens``, and ``notes`` for side facts such as which
@@ -12,7 +14,7 @@ roofline bound applies.
 """
 from __future__ import annotations
 
-from bench import counts
+from bench import harness as H
 from bench import trace as T
 
 DECODE = "decode_multi_paged"
@@ -79,17 +81,18 @@ def prefill_ms_per_ktok(run):
 def decode_roofline(run):
     """Least time of a decode launch's work over its measured device time
     (%).  The least time is the larger of FLOPs over the chips' peak and
-    required bytes over their bandwidth, per chip; the bytes are every
-    weight once, the KV of each active lane's context and the new
-    token's KV."""
+    required bytes over their bandwidth, per chip, both as the family
+    counts them (dense: every weight once, the KV of each active lane's
+    context and the new token's KV)."""
     dms = decode_step_ms(run)
     launches = run.recorder.decodes
     if dms is None or not launches:
         return None
     f_peak, b_peak = run.peak["bf16_flops"], run.peak["hbm_bytes_per_s"]
+    fam = H.family(run.config)
     least, bound = [], {"compute": 0, "memory": 0}
     for ctx in launches:
-        fl, by = counts.decode_step(run.config, ctx)
+        fl, by = fam.decode_step(run, ctx)
         tf, tb = fl / run.chips / f_peak, by / run.chips / b_peak
         least.append(max(tf, tb))
         bound["compute" if tf > tb else "memory"] += 1
@@ -106,9 +109,9 @@ def mfu(run):
     secs = sum(rec.step_s)
     if not secs or not (rec.decodes or rec.prefills):
         return None
-    flops = sum(counts.decode_step(run.config, c)[0] for c in rec.decodes)
-    flops += sum(counts.prefill(run.config, s, e, head=True)
-                 for s, e in rec.prefills)
+    fam = H.family(run.config)
+    flops = sum(fam.decode_step(run, c)[0] for c in rec.decodes)
+    flops += sum(fam.prefill(run, s, e, head=True) for s, e in rec.prefills)
     return 100.0 * flops / (secs * run.chips * run.peak["bf16_flops"])
 
 

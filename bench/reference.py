@@ -1,5 +1,6 @@
 """Plain float32 forward pass of a dense GQA decoder, the yardstick that
-decides ``correct``.
+decides ``correct`` for the ``dense_gqa`` family; other families
+(``bench/families/``) subclass ``Reference`` with their own layer.
 
 RMSNorm, rotary embeddings (rotate-half), optional RMSNorm of q and k
 over the head dimension before RoPE (Qwen3), grouped-query causal
@@ -78,8 +79,9 @@ def _attend(q, k, v, quant: bool):
     return jnp.einsum("hqk,khd->qhd", p, v)
 
 
-def _layer(x, w, dm, quant):
-    """x (B, S, d) float32; w one layer's weights (float32)."""
+def _attention(x, w, dm, quant):
+    """The attention half of a layer with its residual: x (B, S, d)
+    float32; w one layer's weights (float32)."""
     b, s_len, _ = x.shape
     h, k, hd = dm["h"], dm["k"], dm["hd"]
     pos = jnp.arange(s_len)
@@ -92,7 +94,12 @@ def _layer(x, w, dm, quant):
         kk = _rms(kk, w["k_norm"], dm["eps"])
     q, kk = _rope(q, pos, dm["theta"]), _rope(kk, pos, dm["theta"])
     o = jax.lax.map(lambda a: _attend(*a, quant), (q, kk, vv))
-    x = x + _mm(o.reshape(b, s_len, h * hd), w["wo"], quant)
+    return x + _mm(o.reshape(b, s_len, h * hd), w["wo"], quant)
+
+
+def _layer(x, w, dm, quant):
+    """x (B, S, d) float32; w one layer's weights (float32)."""
+    x = _attention(x, w, dm, quant)
     y = _rms(x, w["norm2"], dm["eps"])
     g = _mm(y, w["w_gate"], quant)
     u = _mm(y, w["w_up"], quant)
@@ -104,30 +111,46 @@ def _f32(tree):
 
 
 class Reference:
-    """The float32 forward of one configuration at one seed."""
+    """The float32 forward of one configuration at one seed.
+
+    The four class attributes are what a model family replaces in a
+    subclass: its sizes (``dims(cfg)``, with ``layers``, ``d``, ``v``,
+    ``eps`` and ``tied``), one layer's seeded tensors made alone
+    (``layer_weights(key, l, dm)``), the embedding, final norm and head
+    (``outer_weights(key, dm)``: ``embed``, ``final_norm`` and, untied,
+    ``lm_head``) and one layer's float32 forward (``layer_forward(x, w,
+    dm, quant)``).  The embedding, the layer loop, the head, ``judge`` and
+    the float8 control are the same for every family."""
+
+    dims = staticmethod(W.dims)
+    layer_weights = staticmethod(W.layer)
+    outer_weights = staticmethod(W.outer)
+    layer_forward = staticmethod(_layer)
 
     def __init__(self, cfg: dict, seed: int, *, device=None):
-        self.dm = W.dims(cfg)
+        self.dm = self.dims(cfg)
         self.words = W.seed_words(seed)
         self.device = device or jax.devices()[0]
         dm = self.dm
+        layer_weights, outer_weights, layer_forward = \
+            self.layer_weights, self.outer_weights, self.layer_forward
 
         @jax.jit
         def embed(words, tokens):
-            o = W.outer(W.seed_key(words), dm)
+            o = outer_weights(W.seed_key(words), dm)
             return o["embed"].astype(jnp.float32)[tokens]
 
         @partial(jax.jit, static_argnames="quant")
         def layer(x, words, l, quant):
-            w = _f32(W.layer(W.seed_key(words), l, dm))
-            return _layer(x, w, dm, quant)
+            w = _f32(layer_weights(W.seed_key(words), l, dm))
+            return layer_forward(x, w, dm, quant)
 
         @partial(jax.jit, static_argnames="quant")
         def head(x, words, idx, judged, quant):
             """x (B, S, d); idx (B, T) positions; judged (B, T) token ids
             or -1 (the control: judge its own argmax).  Returns the gap
             below the reference's best logit (B, T) and the judged ids."""
-            o = _f32(W.outer(W.seed_key(words), dm))
+            o = _f32(outer_weights(W.seed_key(words), dm))
             w_out = o["embed"].T if dm["tied"] else o["lm_head"]
             xs = jnp.take_along_axis(x, idx[..., None], axis=1)
             xs = _rms(xs, o["final_norm"], dm["eps"])

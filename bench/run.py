@@ -8,7 +8,8 @@ from ``--seed``, warms up every shape the cell's traffic can use (set-up,
 timed as ``setup_s``), then drives ``Engine.step()`` open-loop for
 ``--seconds`` with the cell's traffic, generated from the seed.  After the
 window the program's state is freed and a sample of the finished requests
-is held against the float32 reference (``bench/reference.py``).
+is held against the float32 reference of the configuration's model family
+(``bench/families/<family>.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
@@ -96,7 +97,7 @@ def main(argv=None) -> int:
     t_warm = time.perf_counter()
     H.warm_up(eng, cell.config, cell.mix, args.seed)
     t_warm_end = time.perf_counter()
-    schedule = generate(cell.mix, vocab=cell.config["vocab_size"],
+    schedule = generate(cell.mix, vocab=H.vocab(cell.config),
                         seed=args.seed, seconds=args.seconds,
                         rate_per_s=cell.params.get("rate_per_s"))
     recorder = trace_dir = None

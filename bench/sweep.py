@@ -38,13 +38,14 @@ def unloaded(eng, cell, seed: int) -> dict:
     import numpy as np
     from repro.serving import Request
     rng = np.random.default_rng([seed, 0x554E])
-    mix = generate(cell.mix, vocab=cell.config["vocab_size"], seed=seed,
+    vocab = H.vocab(cell.config)
+    mix = generate(cell.mix, vocab=vocab, seed=seed,
                    seconds=1000.0, rate_per_s=1.0)
     n = int(np.median([len(it.prompt) for it in mix]))
     out = []
     for _ in range(3):
-        req = Request(prompt=rng.integers(0, cell.config["vocab_size"],
-                                          n).tolist(), max_new_tokens=64)
+        req = Request(prompt=rng.integers(0, vocab, n).tolist(),
+                      max_new_tokens=64)
         t0 = time.perf_counter()
         eng.submit(req)
         stamps = []
@@ -76,7 +77,7 @@ def main(argv=None) -> int:
     print(json.dumps({"unloaded": base, "device": device}), flush=True)
     rows = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
-        sched = generate(cell.mix, vocab=cell.config["vocab_size"],
+        sched = generate(cell.mix, vocab=H.vocab(cell.config),
                          seed=args.seed + 1 + i, seconds=args.seconds,
                          rate_per_s=rate)
         run = H.drive(eng, sched, args.seconds)

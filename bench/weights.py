@@ -1,7 +1,9 @@
 """Model weights made from ``--seed`` by the benchmark, not by the program.
 
-One canonical set per (configuration, seed), named as in the published
-checkpoints of dense GQA models, with shapes::
+The seed machinery that every model family (``bench/families/``) makes
+its tensors with, and the dense GQA family's own tensors.  One canonical
+set per (configuration, seed), named as in the published checkpoints of
+dense GQA models, with shapes::
 
     embed (vocab, d)        lm_head (d, vocab)  [untied models only]
     final_norm (d,)
@@ -106,7 +108,8 @@ def outer(key, dm: dict) -> dict:
     return w
 
 
-def all_layers(key, dm: dict) -> dict:
-    """Every layer's weights stacked on a leading layer axis."""
-    return jax.vmap(lambda l: layer(key, l, dm))(
+def all_layers(key, dm: dict, make=layer) -> dict:
+    """Every layer's weights, as ``make(key, l, dm)`` gives one layer,
+    stacked on a leading layer axis."""
+    return jax.vmap(lambda l: make(key, l, dm))(
         jnp.arange(dm["layers"], dtype=jnp.int32))
