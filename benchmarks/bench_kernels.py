@@ -46,9 +46,10 @@ def main(quick: bool = True) -> list:
     vp = jnp.concatenate([jnp.zeros((1, bs, K, D), vd.dtype),
                           vd.reshape(t_blk, bs, K, D)])
     bt = jnp.arange(1, t_blk + 1, dtype=jnp.int32)[None, :]
-    op = np.asarray(ops.paged_decode_attention(q1, kp, vp, bt, pos))
+    kp, vp = kp[None], vp[None]          # a one-layer stacked pool
+    op = np.asarray(ops.paged_decode_attention(q1, kp, vp, 0, bt, pos))
     us_p = best_of(lambda: np.asarray(
-        ops.paged_decode_attention(q1, kp, vp, bt, pos)))
+        ops.paged_decode_attention(q1, kp, vp, 0, bt, pos)))
     err = float(np.max(np.abs(op - o)))       # must equal the dense result
     ratio = us_p / max(us, 1e-9)
     rows.append(emit("kernel_paged_decode_attention", us_p,

@@ -229,13 +229,14 @@ def _b_bad_lane_scan(ctx: AuditContext):
 
 
 def _b_kernel_decode(ctx: AuditContext):
+    # the layer-stacked pool the decode step carries, read at a traced layer
     from repro.kernels import ops
-    n_blocks, bs, K, hd = ctx.kv_pool_leaf()
+    pool = ctx.cache["attn"]["k"]
     h_pad = ctx.model.plan.h_pad(ctx.cfg)
-    pool = _sds((n_blocks, bs, K, hd), jnp.bfloat16)
-    q = _sds((ctx.n_lanes, h_pad, hd), jnp.bfloat16)
-    return ops.paged_decode_attention, (q, pool, pool, ctx.tables(),
-                                        ctx.lane_i32()), dict(interpret=True)
+    q = _sds((ctx.n_lanes, h_pad, pool.shape[-1]), jnp.bfloat16)
+    return ops.paged_decode_attention, (q, pool, pool, _sds((), jnp.int32),
+                                        ctx.tables(), ctx.lane_i32()), \
+        dict(interpret=True)
 
 
 def _b_kernel_prefill(ctx: AuditContext):
@@ -276,8 +277,8 @@ ENTRYPOINTS: tuple = (
     Entrypoint("pool_bad_lane_scan", "pool", _b_bad_lane_scan,
                doc="NaN/Inf quarantine sweep over written KV positions"),
     Entrypoint("kernel_paged_decode", "kernel", _b_kernel_decode,
-               f32_dot_ok=True,
-               doc="Pallas paged flash-decode (interpret mode)"),
+               doc="Pallas paged decode over the stacked pool, bf16 "
+                   "operands to the MXU (interpret mode)"),
     Entrypoint("kernel_paged_prefill", "kernel", _b_kernel_prefill,
                f32_dot_ok=True,
                doc="Pallas paged prefill kernel (interpret mode)"),

@@ -48,11 +48,13 @@ def decode_attention(q, k, v, positions, *, block_k=512, interpret=None):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pool, v_pool, block_tables, positions, *,
-                           interpret=None):
+def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, positions,
+                           *, interpret=None):
+    """Decode attention over the layer-stacked pool (L, n_blocks, bs, K, D)
+    at ``layer``."""
     if interpret is None:
         interpret = _default_interpret()
-    return _paged_decode(q, k_pool, v_pool, block_tables, positions,
+    return _paged_decode(q, k_pool, v_pool, layer, block_tables, positions,
                          interpret=interpret)
 
 

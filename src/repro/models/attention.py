@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+from repro.kernels.paged_decode_attention import \
+    paged_decode_attention as paged_decode_kernel
 from repro.models import layers as L
 from repro.models.sharding import ShardPlan, shard_map_or_call
 
@@ -559,6 +561,30 @@ def _swa_decode(p, q, k_new, v_new, cache, positions, cfg, plan, kv_idx, scale):
     return plan.constrain(out, ("batch", "embed_act"), cfg), {"k": k_c, "v": v_c}
 
 
+def paged_decode_path(cfg: ArchConfig, plan: ShardPlan, pool_dtype) -> str:
+    """The attention a paged decode step's program is built with:
+    ``"pallas"`` (``kernels/paged_decode_attention``) or ``"xla"``
+    (``paged_attention``, and ``_paged_decode_core`` on a sharded pool).
+
+    The kernel serves a single-device plan on TPU where query head h reads
+    kv head h // G (no padded q or kv heads), the head dim fills whole
+    128-lane rows, the kv heads fill whole packed rows of the pool's dtype
+    (Mosaic cannot slice a lone bf16 head out of a pool block), and the
+    pool holds the compute dtype.  Everything else keeps the XLA gather.
+    """
+    single = plan.mesh is None or plan.mesh.size == 1
+    if not single or jax.default_backend() != "tpu":
+        return "xla"
+    pool_dtype = jnp.dtype(pool_dtype)
+    packing = max(4 // pool_dtype.itemsize, 1)
+    plain = plan.h_pad(cfg) == cfg.n_heads and not plan.kv_padded(cfg)
+    if (plain and cfg.head_dim % 128 == 0
+            and cfg.n_kv_heads % packing == 0
+            and jnp.dtype(plan.compute_dtype) == pool_dtype):
+        return "pallas"
+    return "xla"
+
+
 def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, *,
                     scale: float, kv_idx: jax.Array) -> jax.Array:
     """Decode attention over a paged KV pool (XLA gather path).
@@ -568,11 +594,12 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, positions, *,
     positions: (B,).
 
     The per-sequence logical view is gathered from the pool through the
-    block table, then masked by position.  This XLA gather is what the
-    serving path runs on every backend, TPU included.  The Pallas kernel
-    ``kernels/paged_decode_attention.py`` resolves the same gather in its
-    BlockSpec index map without materialising the view; it is tested
-    against this path and compile-tested for TPU, but not called here.
+    block table, then masked by position: every lane reads all T blocks of
+    its table whatever its context.  ``gqa_decode_paged`` runs this where
+    ``paged_decode_path`` says ``"xla"`` (off TPU, padded heads, other
+    head sizes); on one TPU chip it runs the Pallas kernel
+    ``kernels/paged_decode_attention.py``, which reads only the blocks a
+    lane's position reaches.  This path is the kernel's reference.
     """
     B, H = q.shape[:2]
     bs, K = k_pool.shape[2], k_pool.shape[3]
@@ -818,7 +845,10 @@ def gqa_decode_paged(p: Params, x: jax.Array, cache: Params, layer,
     O(active sequences), independent of pool size.  It lands in place
     only because the caller's layer scan carries the stacked pool (see
     ``Model.decode_step_paged``) and jit donates it: scanning the pool as
-    per-layer slices would copy it whatever the donation.
+    per-layer slices would copy it whatever the donation.  On one TPU chip
+    the attention is the Pallas kernel over the carried pool, which reads
+    only the blocks each lane's position reaches; elsewhere it is the XLA
+    gather (``paged_decode_path``).
     """
     dt = plan.compute_dtype
     h_pad = plan.h_pad(cfg)
@@ -847,8 +877,12 @@ def gqa_decode_paged(p: Params, x: jax.Array, cache: Params, layer,
                 k_new.astype(cache["k"].dtype))
             v_c = cache["v"].at[layer, blk, off].set(
                 v_new.astype(cache["v"].dtype))
-        o = paged_attention(q, k_c, v_c, layer, block_tables, positions,
-                            scale=scale, kv_idx=idx)
+        if paged_decode_path(cfg, plan, k_c.dtype) == "pallas":
+            o = paged_decode_kernel(q, k_c, v_c, layer, block_tables,
+                                    positions, scale=scale)
+        else:
+            o = paged_attention(q, k_c, v_c, layer, block_tables, positions,
+                                scale=scale, kv_idx=idx)
     out = jnp.einsum("bhk,hkd->bd", o, p["w_o"].astype(dt))
     return plan.constrain(out, ("batch", "embed_act"), cfg), {"k": k_c, "v": v_c}
 

@@ -641,6 +641,11 @@ class Model:
                 and cfg.attn_kind == "gqa" and cfg.causal
                 and cfg.input_kind == "tokens")
 
+    def paged_decode_path(self, pool_dtype=jnp.bfloat16) -> str:
+        """``"pallas"`` or ``"xla"``: the attention ``decode_step_paged``
+        is built with over a pool of ``pool_dtype`` on this backend."""
+        return A.paged_decode_path(self.cfg, self.plan, pool_dtype)
+
     def prefill(self, params, inputs):
         """Returns (last-token logits (B, V_pad), cache stacked over layers)."""
         cfg, plan = self.cfg, self.plan

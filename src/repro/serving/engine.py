@@ -41,10 +41,12 @@ Every ``step()`` is one ``engine.step`` profiler span
 ``engine.admit``, ``engine.prefill`` and ``engine.decode``; the last two
 split into ``.prepare`` (host arrays and uploads), ``.launch`` (the jitted
 call's dispatch), ``.sync`` (the device->host readback) and ``.commit``
-(bookkeeping), under the same names in every serving mode.  The profiler
-stamps them on the clock of its device planes.  Span arguments are built
-only while a profiler session records; without one a span costs about a
-microsecond.
+(bookkeeping), under the same names in every serving mode; ``.launch``
+carries ``attn``, the attention the decode program was built with
+(``pallas`` or ``xla``), which ``EngineStats.decode_launches`` counts
+without a profiler.  The profiler stamps them on the clock of its device
+planes.  Span arguments are built only while a profiler session records;
+without one a span costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -115,6 +117,10 @@ class EngineStats:
     # the queue on the host's monotonic clock (submit or re-queue -> claim)
     admitted: int = 0
     queue_wait_s: float = 0.0
+    # decode launches by the attention their program was built with
+    # (``Model.paged_decode_path``): the Pallas kernel or the XLA gather
+    decode_launches: dict = field(
+        default_factory=lambda: {"pallas": 0, "xla": 0})
     completed: list = field(default_factory=list)
     _good_acc: dict = field(default_factory=dict, repr=False)
 
@@ -304,6 +310,9 @@ class Engine:
             self.pool = CachePool(model, self.n_slots, self.max_seq)
             self._prefill_jit = jax.jit(model.prefill)
             self._decode_jit = jax.jit(model.decode_step)
+        self._decode_attn = model.paged_decode_path(
+            jax.tree.leaves(self.pool.cache)[0].dtype) if self.paged \
+            else "xla"
         self._bind_spec()
 
     # -- speculative decode (drafter lifecycle) ----------------------------
@@ -736,6 +745,13 @@ class Engine:
     def _prefill_args(rows: int, tokens: int, finished: int) -> dict:
         return dict(rows=rows, tokens=tokens, finished=finished)
 
+    def _count_launch(self, launch) -> None:
+        """Count a decode launch by its attention path, and name the path
+        on its span while a profiler records."""
+        self.stats.decode_launches[self._decode_attn] += 1
+        if TraceAnnotation.is_enabled():
+            launch.set_metadata(attn=self._decode_attn)
+
     def _decode_args(self, horizon: int, preempted: int) -> dict:
         lane_of = self.pool.lane_of if self.paged else self.pool.slot_of
         return dict(lanes=len(self.active),
@@ -1040,7 +1056,8 @@ class Engine:
                 args = (self.pool.last_tokens_dev(), self.pool.positions(),
                         self.pool.tables(), jnp.asarray(mask),
                         jnp.asarray(budget_arr), jnp.asarray(eos_arr))
-            with TraceAnnotation("engine.decode.launch"):
+            with TraceAnnotation("engine.decode.launch") as launch:
+                self._count_launch(launch)
                 toks, emitted, _, (tok_f, pos_f, _, _), self.pool.cache = \
                     self._decode_multi_jit(
                         self.params, self.pool.cache, *args,
@@ -1108,7 +1125,8 @@ class Engine:
                 hist = self.pool.hist_dev()
                 state = (self.pool.last_tokens_dev(), self.pool.positions(),
                          self.pool.tables())
-            with TraceAnnotation("engine.decode.launch"):
+            with TraceAnnotation("engine.decode.launch") as launch:
+                self._count_launch(launch)
                 toks, em, acc, (tok_f, pos_f, _, _), self.pool.cache, \
                     self.pool.draft_cache, hist_f = self._decode_spec_jit(
                         self.params, self.pool.cache, self._d_params,
@@ -1184,7 +1202,8 @@ class Engine:
                         seeds[ln] = self._req_seed(req)
                     t, tks, seeds = (jnp.asarray(temps), jnp.asarray(tks),
                                      jnp.asarray(seeds))
-            with TraceAnnotation("engine.decode.launch"):
+            with TraceAnnotation("engine.decode.launch") as launch:
+                self._count_launch(launch)
                 logits, self.pool.cache = self._decode_jit(
                     self.params, self.pool.cache, tokens, positions)
                 v = self.model.cfg.vocab_size

@@ -145,6 +145,11 @@ def test_spans_under_a_profiler(tiny_model, tiny_params, tmp_path, mode):
     # each decode span saw what its launch saw
     assert [(d[0][3]["lanes"], d[0][3]["ctx_tokens"])
             for d in decodes] == launches
+    # each launch names the attention its program was built with: off TPU
+    # the XLA gather, as the counter says
+    assert [c[0][3]["attn"] for d in decodes for c in d[1]
+            if c[0][2] == "engine.decode.launch"] == ["xla"] * len(launches)
+    assert eng.stats.decode_launches == {"pallas": 0, "xla": len(launches)}
     horizon = eng.horizon if eng.paged else 1
     assert all(d[0][3]["horizon"] == horizon and d[0][3]["preempted"] == 0
                for d in decodes)
@@ -178,6 +183,8 @@ def test_no_span_arguments_without_a_profiler(tiny_model, tiny_params,
     assert len(eng.stats.completed) == 5
     assert calls == []
     assert eng.stats.admitted == 5 and eng.stats.queue_wait_s > 0
+    assert eng.stats.decode_launches["pallas"] == 0
+    assert eng.stats.decode_launches["xla"] > 0
 
 
 def test_queue_wait_restarts_on_preemption(tiny_model, tiny_params):
@@ -193,3 +200,30 @@ def test_queue_wait_restarts_on_preemption(tiny_model, tiny_params):
     assert eng.queue[0].req_id == rid and eng.queue[0].queued_ns > before
     eng.step()
     assert eng.stats.admitted == 3
+
+
+def test_decode_attention_dispatch(monkeypatch):
+    """The Pallas decode kernel is chosen from what the code observes: a
+    TPU backend, a single-device plan, query head h on kv head h // G, a
+    lane-multiple head dim and a pool of the compute dtype whose kv heads
+    fill packed rows.  Anything else keeps the XLA gather."""
+    from types import SimpleNamespace
+
+    from repro.models.attention import paged_decode_path
+    from repro.models.sharding import ShardPlan
+    cfg = get_config("qwen3-1.7b")
+    one = local_plan(param_dtype=jnp.bfloat16)
+    assert paged_decode_path(cfg, one, jnp.bfloat16) == "xla"       # CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged_decode_path(cfg, one, jnp.bfloat16) == "pallas"
+    # 16 query heads over a 3-way model axis pad to 18
+    padded = ShardPlan(mesh=SimpleNamespace(shape={"model": 3}, size=3),
+                       tp_axis="model", shard_paged_pool=False)
+    assert padded.h_pad(cfg) != cfg.n_heads
+    assert paged_decode_path(cfg, padded, jnp.bfloat16) == "xla"
+    # head dim 64; one bf16 kv head; a float32 pool under bf16 compute
+    assert paged_decode_path(get_config("granite-moe-3b-a800m"), one,
+                             jnp.bfloat16) == "xla"
+    assert paged_decode_path(cfg.replace(n_heads=8, n_kv_heads=1), one,
+                             jnp.bfloat16) == "xla"
+    assert paged_decode_path(cfg, one, jnp.float32) == "xla"

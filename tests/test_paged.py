@@ -23,23 +23,46 @@ def arr(rng, *s, dtype=jnp.float32):
 # kernel: paged == dense at equal logical context
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,H,K,D,bs,T", [
-    (2, 8, 2, 64, 16, 8),     # GQA 4:1, sequences span 8 blocks
-    (1, 4, 4, 128, 32, 4),    # MHA
-    (3, 4, 1, 64, 16, 8),     # MQA
-])
-def test_paged_decode_matches_ref(B, H, K, D, bs, T):
+def _stacked(rng, pool, layer=1, layers=3):
+    """A layer-stacked pool holding ``pool`` at ``layer``, noise elsewhere:
+    the decode kernel reads the layer it is given and no other."""
+    noise = arr(rng, layers, *pool.shape, dtype=pool.dtype)
+    return noise.at[layer].set(pool)
+
+
+@pytest.mark.parametrize("B,H,K,D,bs,T,positions", [
+    (2, 8, 2, 64, 16, 8, None),       # GQA 4:1, sequences span 8 blocks
+    (1, 4, 4, 128, 32, 4, None),      # MHA
+    (3, 4, 1, 64, 16, 8, None),       # MQA
+    # 256-position compute blocks: two parked lanes (position 0, table
+    # all 0), a context ending on a compute-block edge and one 1 past it
+    (4, 8, 4, 128, 16, 32, (0, 0, 255, 256)),
+    (2, 8, 4, 128, 16, 32, (511, 300)),   # a full table
+], ids=["gqa4", "mha", "mqa", "parked+edges", "full-table"])
+def test_paged_decode_matches_ref(B, H, K, D, bs, T, positions):
+    """The stacked-pool kernel at layer 1 against the float32 oracle on
+    that layer, and against the XLA gather path the serving step runs
+    off TPU."""
+    from repro.models.attention import paged_attention
     rng = np.random.default_rng(B * 10 + T)
     n_blocks = 1 + B * T
     kp, vp = arr(rng, n_blocks, bs, K, D), arr(rng, n_blocks, bs, K, D)
     q = arr(rng, B, H, D)
     # ragged positions, scrambled (non-contiguous) physical block layout
-    pos = jnp.asarray(rng.integers(0, T * bs, B), jnp.int32)
+    if positions is None:
+        pos = rng.integers(0, T * bs, B)
+    else:
+        pos = np.asarray(positions)
     ids = rng.permutation(np.arange(1, n_blocks))[: B * T].reshape(B, T)
-    bt = jnp.asarray(ids, jnp.int32)
-    o = ops.paged_decode_attention(q, kp, vp, bt, pos)
+    ids[pos == 0] = 0                  # parked lanes point at block 0
+    bt, pos = jnp.asarray(ids, jnp.int32), jnp.asarray(pos, jnp.int32)
+    kst, vst = _stacked(rng, kp), _stacked(rng, vp)
+    o = ops.paged_decode_attention(q, kst, vst, 1, bt, pos)
     o_ref = ref.paged_decode_attention_ref(q, kp, vp, bt, pos)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=1e-5)
+    o_xla = paged_attention(q, kst, vst, 1, bt, pos, scale=1 / np.sqrt(D),
+                            kv_idx=jnp.arange(H) // (H // K))
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_xla), atol=1e-5)
 
 
 def test_paged_decode_matches_dense_flash_decode():
@@ -59,7 +82,8 @@ def test_paged_decode_matches_dense_flash_decode():
     bt = perm.reshape(B, T)
     kp = kp.at[bt.reshape(-1)].set(k.reshape(B * T, bs, K, D))
     vp = vp.at[bt.reshape(-1)].set(v.reshape(B * T, bs, K, D))
-    o_paged = ops.paged_decode_attention(q, kp, vp,
+    o_paged = ops.paged_decode_attention(q, _stacked(rng, kp),
+                                         _stacked(rng, vp), 1,
                                          jnp.asarray(bt, jnp.int32), pos)
     np.testing.assert_allclose(np.asarray(o_paged), np.asarray(o_dense),
                                atol=1e-5)
@@ -73,10 +97,10 @@ def test_paged_decode_masks_future():
     q = arr(rng, B, H, D)
     bt = jnp.arange(1, T + 1, dtype=jnp.int32)[None]
     pos = jnp.asarray([21], jnp.int32)
-    o1 = ops.paged_decode_attention(q, kp, vp, bt, pos)
+    o1 = ops.paged_decode_attention(q, kp[None], vp[None], 0, bt, pos)
     kp2 = kp.at[2, 6:].set(999.0).at[3].set(999.0).at[4].set(999.0)
     vp2 = vp.at[2, 6:].set(999.0).at[3].set(999.0).at[4].set(999.0)
-    o2 = ops.paged_decode_attention(q, kp2, vp2, bt, pos)
+    o2 = ops.paged_decode_attention(q, kp2[None], vp2[None], 0, bt, pos)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
 
 
@@ -113,7 +137,8 @@ def test_paged_prefill_chunk_equals_decode_steps():
                      .reshape(B, T), jnp.int32)
     o_chunk = ops.paged_prefill_attention(q, kp, vp, bt, starts)
     for c in range(C):
-        o_one = ops.paged_decode_attention(q[:, c], kp, vp, bt, starts + c)
+        o_one = ops.paged_decode_attention(q[:, c], kp[None], vp[None], 0,
+                                           bt, starts + c)
         np.testing.assert_allclose(np.asarray(o_chunk[:, c]),
                                    np.asarray(o_one), atol=1e-5)
 
@@ -152,7 +177,8 @@ def test_paged_verify_equals_decode_steps():
                      .reshape(B, T), jnp.int32)
     o = ops.paged_verify_attention(q, kp, vp, bt, pos)
     for i in range(Q):
-        o_one = ops.paged_decode_attention(q[:, i], kp, vp, bt, pos + i)
+        o_one = ops.paged_decode_attention(q[:, i], kp[None], vp[None], 0,
+                                           bt, pos + i)
         np.testing.assert_allclose(np.asarray(o[:, i]), np.asarray(o_one),
                                    atol=1e-5)
 

@@ -76,7 +76,7 @@ def test_paged_decode_lse_kernel_matches_full_pool():
     bt = jnp.asarray(rng.permutation(np.arange(1, nb))[:B * T]
                      .reshape(B, T), jnp.int32)
     pos = jnp.asarray([5, 17, 30], jnp.int32)
-    ref = paged_decode_attention(q, kp, vp, bt, pos)
+    ref = paged_decode_attention(q, kp[None], vp[None], 0, bt, pos)
     for shards in (2, 4):
         nb_loc = nb // shards
         os_, lses = [], []
